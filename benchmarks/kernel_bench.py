@@ -12,6 +12,7 @@ import numpy as np
 
 from repro.core import engine as eng
 from repro.core import graph as G
+from repro.launch.roofline import DRYRUN_DEVICE, peaks
 
 from . import common
 
@@ -93,7 +94,7 @@ def run(graphs=None, emit=common.csv_line):
 # bytes — the paper graphs belong to the compiled-TPU path, not a CPU
 # correctness sweep.)
 
-HBM_BW = 819e9
+HBM_BW = peaks(DRYRUN_DEVICE).hbm_bw   # the modeled chip: TPU v5e
 LAUNCH_S = 1e-6
 SWEEP_LAUNCHES_SYNC = 3    # spmv + apply/select + reduce
 SWEEP_LAUNCHES_FUSED = 1

@@ -20,6 +20,8 @@ import json
 
 import numpy as np
 
+from repro.launch.compile_cache import enable_compile_cache
+
 from . import algo_suite, async_vs_sync, common, dist_async, \
     dist_batched, fig5_cycles, fig6_power, kernel_bench, lm_bench, \
     serve_latency
@@ -38,6 +40,7 @@ def main() -> None:
                              "kernel", "kernel_fused", "lm", "serve",
                              "algo_suite"])
     args = ap.parse_args()
+    enable_compile_cache()
 
     graphs = common.load_graphs(args.scale)
     out = {"meta": {"scale": args.scale,

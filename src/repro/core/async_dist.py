@@ -67,7 +67,7 @@ from . import semiring as sr
 from .engine import Prepared, _apply
 from .. import resilience
 from .placement import (DistStats, ShardedBatch,  # noqa: F401 (re-export)
-                        _shard_map, _spmv_ref, shard_batched_inputs)
+                        _spmv_ref, shard_batched_inputs)
 
 
 def distributed_async_run_batched(
@@ -122,12 +122,12 @@ def distributed_async_run_batched(
     max_rounds = -(-int(max_sweeps) // k)
 
     @functools.partial(
-        _shard_map, mesh=sb.mesh,
+        jax.shard_map, mesh=sb.mesh,
         in_specs=(P("graph"), P("graph"), P("graph"), P("graph"),
                   P("query", "graph"), P("query")),
         out_specs=(P("query", "graph"), P("query"), P("query"), P(),
                    P("graph")),
-        check_rep=False)
+        check_vma=False)
     def run(vals_l, cols_l, nnz_l, valid_l, x_l, qlive_l):
         row0 = jax.lax.axis_index("graph") * rl
         valid_b = valid_l[None]
@@ -228,7 +228,7 @@ def distributed_async_run_batched(
         cut_fraction=p.clustering.cut_fraction,
         mesh_shape=(d_g, d_q), query_sweeps=sweeps_q,
         halo_exchanges=int(exch[0]), local_sweeps=k,
-        shard_sweeps=np.asarray(shard_sweeps))
+        shard_sweeps=np.asarray(shard_sweeps), **sb.placement())
     return x[:Q, : p.r_pad], stats
 
 

@@ -37,6 +37,7 @@ from . import semiring as sr
 from .cluster import Clustering, cluster_graph, identity_clustering
 from .graph import Graph, to_bsr
 from ..kernels import ops
+from ..kernels.bsr_spmv import lane_tiles
 from ..kernels.spec import KernelSpec, as_kernel_spec
 from .. import resilience
 
@@ -46,7 +47,7 @@ class Prepared:
     """Clustered, permuted, device-resident graph + engine metadata."""
 
     # device arrays
-    vals: jnp.ndarray       # (r_pad, K, B, B) f32
+    vals: jnp.ndarray       # (r_pad, B, K*B) f32 — destination-major tiles
     cols: jnp.ndarray       # (r_pad, K) i32
     nnz: jnp.ndarray        # (r_pad,) i32
     valid: jnp.ndarray      # (r_pad, B) bool — real (non-padding) vertices
@@ -167,7 +168,7 @@ jax.tree_util.register_pytree_node(
 # restart deserializes this instead of re-running the whole compile
 # pipeline (profile → cluster → analyze → place → BSR build).
 
-PREPARED_FORMAT_VERSION = 2  # v2: + row_edges/row_ext (fused-path counters)
+PREPARED_FORMAT_VERSION = 3  # v3: destination-major (r, B, K*B) tiles
 
 # Payload framing: serialized plans carry a content digest so the store
 # can tell a corrupt/truncated disk entry from a healthy one and
@@ -279,12 +280,15 @@ def prepare(g: Graph, semiring_name: str, b: int = 32,
     s = min(c.num_clusters, bsr.r)
     gb = (bsr.r + s - 1) // s
     r_pad = s * gb
-    k = bsr.k_max
-    vals = np.full((r_pad, k, b, b), float(ring.zero), dtype=np.float32)
+    # tile slots padded so a row-block's sources fill whole 128-lane
+    # columns: the Pallas kernels then take the device image as it is
+    m = lane_tiles(b)
+    k = -(-bsr.k_max // m) * m
+    vals = np.full((r_pad, b, k * b), float(ring.zero), dtype=np.float32)
     cols = np.zeros((r_pad, k), dtype=np.int32)
     nnz = np.zeros(r_pad, dtype=np.int32)
-    vals[: bsr.r] = bsr.block_vals
-    cols[: bsr.r] = bsr.block_cols
+    vals[: bsr.r, :, : bsr.k_max * b] = bsr.block_vals
+    cols[: bsr.r, : bsr.k_max] = bsr.block_cols
     nnz[: bsr.r] = bsr.block_nnz
 
     valid = np.zeros((r_pad, b), dtype=bool)

@@ -163,6 +163,12 @@ class BsrGraph:
     column tiles.  Padding tiles point at col-block 0 and hold the
     semiring's ⊕-identity so they are arithmetic no-ops (the hardware
     analogue: an empty FIFO slot).
+
+    Tile values are stored destination-major: ``block_vals[r, i, k*b+j]``
+    is the weight from source ``j`` of tile ``k`` into row ``i`` of
+    row-block ``r``.  With the sources on the minor axis a row-block's
+    tiles fill whole 128-lane TPU vector rows; a (.., b, b) minor shape
+    would be padded to 128 lanes, 8x the bytes at b=16.
     """
 
     n: int              # logical vertex count (pre-padding)
@@ -170,7 +176,7 @@ class BsrGraph:
     r: int              # number of row/col blocks  (n_pad / b)
     k_max: int          # max nonempty tiles per row-block
     block_cols: np.ndarray   # (r, k_max) int32
-    block_vals: np.ndarray   # (r, k_max, b, b) float32
+    block_vals: np.ndarray   # (r, b, k_max*b) float32
     block_nnz: np.ndarray    # (r,) int32 — nonempty tile count per row-block
     edge_nnz: np.ndarray     # (r,) int64 — true edge count per row-block
     pad_value: float
@@ -217,7 +223,7 @@ def to_bsr(g: Graph, b: int, pad_value: float = 0.0,
     np.add.at(block_nnz, u_rb, 1)
     k_max = max(int(block_nnz.max()) if len(uniq) else 1, 1)
     block_cols = np.zeros((r, k_max), dtype=np.int32)
-    block_vals = np.full((r, k_max, b, b), pad_value, dtype=np.float32)
+    block_vals = np.full((r, b, k_max * b), pad_value, dtype=np.float32)
     # slot of each unique tile within its row-block (uniq is sorted by key,
     # hence grouped by rb in order)
     first_idx = np.searchsorted(u_rb, np.arange(r))
@@ -225,7 +231,7 @@ def to_bsr(g: Graph, b: int, pad_value: float = 0.0,
     block_cols[u_rb, slot] = u_cb.astype(np.int32)
     # scatter edge values into their tile
     e_slot = slot[tile_of_edge]
-    block_vals[rb, e_slot, src % b, dst % b] = g.weights
+    block_vals[rb, src % b, e_slot * b + dst % b] = g.weights
     edge_nnz = np.zeros(r, dtype=np.int64)
     np.add.at(edge_nnz, rb, 1)
     return BsrGraph(n=g.n, b=b, r=r, k_max=k_max, block_cols=block_cols,
@@ -239,7 +245,7 @@ def bsr_to_dense(bsr: BsrGraph) -> np.ndarray:
     for rb in range(bsr.r):
         for k in range(int(bsr.block_nnz[rb])):
             cb = int(bsr.block_cols[rb, k])
-            tile = bsr.block_vals[rb, k]
+            tile = bsr.block_vals[rb, :, k * bsr.b:(k + 1) * bsr.b]
             cur = a[rb * bsr.b:(rb + 1) * bsr.b, cb * bsr.b:(cb + 1) * bsr.b]
             if bsr.pad_value == 0.0:
                 a[rb * bsr.b:(rb + 1) * bsr.b,

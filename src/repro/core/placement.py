@@ -29,11 +29,6 @@ import jax.numpy as jnp
 import numpy as np
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
-# jax.shard_map landed in 0.5.x; this container ships 0.4.x
-_shard_map = getattr(jax, "shard_map", None)
-if _shard_map is None:  # pragma: no cover - version dependent
-    from jax.experimental.shard_map import shard_map as _shard_map
-
 from . import semiring as sr
 from .engine import Prepared, _apply
 from .. import resilience
@@ -62,7 +57,11 @@ def make_graph_mesh(num_devices: Optional[int] = None,
         raise ValueError(
             f"query_axis={q} does not divide {n} devices; pick a "
             f"divisor of the device count (see factor_query_axis)")
-    return jax.make_mesh((n // q, q), ("graph", "query"))
+    # Auto axes: the engines place data through shard_map specs, and
+    # their results are indexed on the host like any replicated array
+    auto = jax.sharding.AxisType.Auto
+    return jax.make_mesh((n // q, q), ("graph", "query"),
+                         axis_types=(auto, auto))
 
 
 def factor_query_axis(num_devices: int, num_queries: int) -> int:
@@ -94,6 +93,8 @@ class DistStats:
     shard_sweeps: Optional[np.ndarray] = None  # per-"graph"-shard active
     #                                            local sweeps (self-timed
     #                                            rate of each shard)
+    plan_devices: int = 1       # devices holding a shard of the plan rows
+    plan_shard_rows: int = 0    # plan rows resident on each of them
 
 
 def _pad_rows(arr: np.ndarray, rows: int) -> np.ndarray:
@@ -122,10 +123,10 @@ class ShardedBatch:
     r_pad: int              # rows padded to a multiple of d_g
     q_pad: int              # queries padded to a multiple of d_q
     q: int                  # real (un-padded) query count
-    vals: np.ndarray
-    cols: np.ndarray
-    nnz: np.ndarray
-    valid: np.ndarray
+    vals: jax.Array         # plan rows, placed P("graph") on the mesh
+    cols: jax.Array
+    nnz: jax.Array
+    valid: jax.Array
     x0: np.ndarray          # (q_pad, r_pad, B)
     qlive: np.ndarray       # (q_pad,) — padding queries start converged
 
@@ -134,6 +135,12 @@ class ShardedBatch:
         frontier (summed over its resident query rows)."""
         return (self.r_pad // self.d_g) * b * 4.0 * (self.d_g - 1) * \
             (self.q_pad // self.d_q)
+
+    def placement(self) -> dict:
+        """Where the plan rows live: ``DistStats`` placement fields."""
+        return dict(plan_devices=len(self.vals.sharding.device_set),
+                    plan_shard_rows=int(
+                        self.vals.addressable_shards[0].data.shape[0]))
 
 
 def shard_batched_inputs(p: Prepared, x0: jnp.ndarray,
@@ -168,10 +175,11 @@ def shard_batched_inputs(p: Prepared, x0: jnp.ndarray,
     d_q = shape.get("query", 1)
 
     r_pad = ((p.r_pad + d_g - 1) // d_g) * d_g
-    vals = _pad_rows(np.asarray(p.vals), r_pad)
-    cols = _pad_rows(np.asarray(p.cols), r_pad)
-    nnz = _pad_rows(np.asarray(p.nnz), r_pad)
-    valid = _pad_rows(np.asarray(p.valid), r_pad)
+    # each device receives only its own rows of the plan
+    rows = NamedSharding(mesh, P("graph"))
+    vals, cols, nnz, valid = (
+        jax.device_put(_pad_rows(np.asarray(a), r_pad), rows)
+        for a in (p.vals, p.cols, p.nnz, p.valid))
     q_pad = ((Q + d_q - 1) // d_q) * d_q
     x0 = np.asarray(x0)
     x0 = np.concatenate(
@@ -217,10 +225,10 @@ def distributed_sync_run(
     tol = jnp.float32(tol)
 
     @functools.partial(
-        _shard_map, mesh=mesh,
+        jax.shard_map, mesh=mesh,
         in_specs=(P("graph"), P("graph"), P("graph"), P("graph"),
                   P("graph")),
-        out_specs=(P("graph"), P(), P()), check_rep=False)
+        out_specs=(P("graph"), P(), P()), check_vma=False)
     def run(vals_l, cols_l, nnz_l, valid_l, x_l):
         def cond(st):
             i, x_loc, done = st
@@ -285,11 +293,11 @@ def distributed_sync_run_batched(
     tol = jnp.float32(tol)
 
     @functools.partial(
-        _shard_map, mesh=sb.mesh,
+        jax.shard_map, mesh=sb.mesh,
         in_specs=(P("graph"), P("graph"), P("graph"), P("graph"),
                   P("query", "graph"), P("query")),
         out_specs=(P("query", "graph"), P("query"), P("query")),
-        check_rep=False)
+        check_vma=False)
     def run(vals_l, cols_l, nnz_l, valid_l, x_l, qlive_l):
         spmv = jax.vmap(lambda xq: _spmv_ref(vals_l, cols_l, nnz_l, xq,
                                              semiring=p.semiring))
@@ -334,7 +342,8 @@ def distributed_sync_run_batched(
         halo_bytes_per_sweep=sb.halo_bytes_per_exchange(p.b),
         cut_fraction=p.clustering.cut_fraction,
         mesh_shape=(d_g, d_q), query_sweeps=sweeps_q,
-        halo_exchanges=straggler)  # bulk-synchronous: one per sweep
+        halo_exchanges=straggler,  # bulk-synchronous: one per sweep
+        **sb.placement())
     return x[:Q, : p.r_pad], stats
 
 
@@ -355,11 +364,11 @@ def lower_distributed(p: Prepared, mesh: Mesh, apply_kind: str = "relax",
 
     def one_sweep(vals, cols, nnz, valid, x):
         @functools.partial(
-            _shard_map, mesh=mesh,
+            jax.shard_map, mesh=mesh,
             in_specs=(P("graph"),) * 4 + (
                 P("query", "graph") if batch else P("graph"),),
             out_specs=P("query", "graph") if batch else P("graph"),
-            check_rep=False)
+            check_vma=False)
         def sweep(vals_l, cols_l, nnz_l, valid_l, x_l):
             if batch:
                 xg = jax.lax.all_gather(x_l, "graph", axis=1, tiled=True)
@@ -378,7 +387,8 @@ def lower_distributed(p: Prepared, mesh: Mesh, apply_kind: str = "relax",
         return sweep(vals, cols, nnz, valid, x)
 
     specs = [
-        jax.ShapeDtypeStruct((r_pad, p.k_max, p.b, p.b), jnp.float32, sharding=shard),
+        jax.ShapeDtypeStruct((r_pad, p.b, p.k_max * p.b), jnp.float32,
+                             sharding=shard),
         jax.ShapeDtypeStruct((r_pad, p.k_max), jnp.int32, sharding=shard),
         jax.ShapeDtypeStruct((r_pad,), jnp.int32, sharding=shard),
         jax.ShapeDtypeStruct((r_pad, p.b), jnp.bool_, sharding=shard),

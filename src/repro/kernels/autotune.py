@@ -16,11 +16,14 @@ candidate on a seeded ~25%-dense calibration frontier.  The winner is
 deterministic for a given seed and measurement function: ties break
 toward the smallest (block_size, rows_per_step).
 
-Each tuning record carries a roofline cross-check from
-``launch.roofline.kernel_roofline``: ``roofline_agrees`` is True when
-the measured time is at or above the modeled lower bound (a measurement
-*below* the roofline means the harness mis-timed — flagged, never used
-to override the measurement).
+Each tuning record names the device it was measured on and carries a
+roofline cross-check from ``launch.roofline.kernel_roofline`` on that
+device's published peaks: ``roofline_agrees`` is True when the measured
+time is at or above the modeled lower bound (a measurement *below* the
+roofline means the harness mis-timed — flagged, never used to override
+the measurement).  A device without published peaks (the CPU of the
+interpret-mode tests) gets no model: ``modeled_s`` and
+``roofline_agrees`` are None.
 
 The caller (``core/api.GraphProcessor``) caches the returned record in
 the PlanStore keyed by ``(fingerprint, PlanKey(kernel=spec))`` so warm
@@ -36,7 +39,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-from ..launch.roofline import kernel_roofline
+from ..launch.roofline import PEAKS, kernel_roofline
 from . import ops
 from .bsr_spmv import _init_val
 from .spec import KernelSpec
@@ -96,7 +99,7 @@ def _calibration_inputs(p, seed: int, apply_kind: str):
     return x, act, damping, tol, inv_n
 
 
-def _modeled_seconds(p, act, fused: bool) -> dict:
+def _modeled_seconds(p, act, fused: bool, device_kind: str) -> dict:
     """Roofline lower bound for one calibration sweep: bytes follow the
     tiles actually walked (active rows for the fused kernel, all rows
     unfused) plus the resident x image; flops are semiring MACs."""
@@ -109,7 +112,7 @@ def _modeled_seconds(p, act, fused: bool) -> dict:
     tile_bytes = b * b * 4 + 4 + 4          # vals + col index + nnz amort
     hbm = tiles * tile_bytes + float(p.r_pad) * b * 4 * 3  # x in, x/y out
     flops = tiles * 2.0 * b * b
-    return kernel_roofline(flops, hbm)
+    return kernel_roofline(flops, hbm, device_kind)
 
 
 def autotune_spmv(p, spec: KernelSpec, seed: int = 0, iters: int = 3,
@@ -141,13 +144,18 @@ def autotune_spmv(p, spec: KernelSpec, seed: int = 0, iters: int = 3,
 
     t_best, best = min(
         results, key=lambda r: (r[0], r[1].block_size, r[1].rows_per_step))
-    model = _modeled_seconds(p, act, spec.fuse_frontier)
+    kind = jax.devices()[0].device_kind
+    modeled = (_modeled_seconds(p, act, spec.fuse_frontier,
+                                kind)["modeled_s"]
+               if kind in PEAKS else None)
     return {
         "block_size": int(best.block_size),
         "rows_per_step": int(best.rows_per_step),
+        "device_kind": kind,
         "measured_s": t_best,
-        "modeled_s": model["modeled_s"],
-        "roofline_agrees": bool(t_best >= model["modeled_s"]),
+        "modeled_s": modeled,
+        "roofline_agrees": (None if modeled is None
+                            else bool(t_best >= modeled)),
         "seed": int(seed),
         "candidates": [
             {"block_size": int(c.block_size),

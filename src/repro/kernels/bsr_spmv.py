@@ -4,58 +4,49 @@ Paper mapping.  The NALE is a MAC-plus-comparator engine fed by FIFOs; a
 NALE in *cluster mode* executes a whole node cluster.  After the clustering
 pass densifies edges into B×B tiles (see ``core/cluster.py``), one tile is
 exactly one cluster-mode NALE work item: a dense semiring MAC between a
-tile of edges and a block of source-node values.  The systolic array of
-NALEs becomes the MXU (plus_times) / VPU (min_plus, max_min), VMEM plays
-the NALE-local FIFO store, and the *self-timed* property — work driven by
-actual data, not worst case — is realized by bounding each row-block's
-inner loop with its true tile count (``block_nnz``): empty FIFO slots cost
-nothing.
+tile of edges and a block of source-node values.  The array of NALEs
+becomes the VPU, VMEM plays the NALE-local FIFO store, and the
+*self-timed* property — work driven by actual data, not worst case — is
+realized by bounding each row-block's tiles with its true tile count
+(``block_nnz``): chunks past it are neither fetched nor combined, so
+empty FIFO slots cost nothing.
 
-Layout (ELL-of-tiles):
-  block_vals : (R, K, B, B)  tile values, padded with the ⊕-identity
-  block_cols : (R, K) int32  col-block index per tile
-  block_nnz  : (R,)   int32  true tile count per row-block
-  x          : (C, B)        input node values (block layout)
-  y          : (R, B)        output
+Layout (ELL-of-tiles, destination-major; see ``core.graph.BsrGraph``):
+  block_vals : (R, B, K*B)  tile values, padded with the ⊕-identity;
+               [r, i, k*B+j] is source j of tile k into row i
+  block_cols : (R, K) int32 col-block index per tile
+  block_nnz  : (R,)   int32 true tile count per row-block
+  x          : (C, B)       input node values (block layout)
+  y          : (R, B)       output
 
-Grid: ``(R, K // bk)`` — row-blocks × tile-chunks.  The tile-chunk axis is
-innermost (sequential on TPU), accumulating into the output block that
-stays resident in VMEM; BlockSpecs stage (1, bk, B, B) value slabs
-HBM→VMEM per step.  ``x`` is kept whole in VMEM (graph shards are sized so
-a shard's node values fit: C·B·4 bytes ≤ a few MB — the same constraint
-the paper's per-NALE FIFO capacity imposes).
+Every blocked operand fits the TPU's (8, 128) f32 register tile: a grid
+step takes a group of 8 row-blocks (one sublane tile) and a chunk of
+tiles whose sources fill whole 128-lane columns.  The gather
+``x[block_cols]`` is one XLA op in the wrapper, producing the lane-dense
+(R, K*B) source operand, so ``x`` never has to fit in VMEM.  The
+per-step tile bound is scalar-prefetched into SMEM: it steers the
+``pl.when`` skip and clamps the chunk index of the block maps onto the
+last live chunk, so dead chunks are not even fetched.  Rows whose own
+count ends inside a chunk mask the rest of it to the ⊕-identity.
 """
 
 from __future__ import annotations
 
 import functools
+import math
 
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
+SUBLANES = 8   # row-blocks per grid-step group: one f32 sublane tile
+LANES = 128
+
 
 def _init_val(semiring: str) -> float:
     return {"plus_times": 0.0, "min_plus": jnp.inf,
             "max_min": 0.0, "min_select": jnp.inf}[semiring]
-
-
-def _tile_combine(semiring: str, tile, xb):
-    """One NALE MAC: combine (bk,B,B) tiles with (bk,B) gathered x blocks,
-    reduce over the tile-chunk and source axes -> (B,) partial."""
-    if semiring == "plus_times":
-        # (bk,B,B) @ (bk,B) -> (bk,B) -> (B,)
-        return jnp.einsum("kij,kj->i", tile, xb,
-                          preferred_element_type=jnp.float32)
-    if semiring == "min_plus":
-        return jnp.min(tile + xb[:, None, :], axis=(0, 2))
-    if semiring == "max_min":
-        return jnp.max(jnp.minimum(tile, xb[:, None, :]), axis=(0, 2))
-    if semiring == "min_select":
-        t = jnp.where(jnp.isfinite(tile), xb[:, None, :], jnp.inf)
-        return jnp.min(t, axis=(0, 2))
-    raise ValueError(semiring)
 
 
 def _acc(semiring: str, a, b):
@@ -66,41 +57,76 @@ def _acc(semiring: str, a, b):
     return jnp.maximum(a, b)
 
 
-def _gather_combine(semiring: str, bk: int, nnz, base, tile, cols, x_ref):
-    """Gather the source-node blocks for one (bk,) tile chunk and combine.
-    K is small (≤ bk), so an unrolled gather over bk dynamic row loads
-    maps to bk VMEM dynamic slices."""
-    xb = jnp.stack([pl.load(x_ref, (pl.dslice(cols[t], 1), slice(None)))[0]
-                    for t in range(bk)])  # (bk, B)
-    # mask padded lanes of the *final* chunk with ⊕-identity values —
-    # padding tiles already hold identities, but their gathered x could
-    # combine under min_select; keep it exact:
-    lane = jnp.arange(bk) + base
-    live = (lane < nnz)[:, None, None]
-    tile = jnp.where(live, tile, _init_val(semiring))
-    return _tile_combine(semiring, tile, xb)
+def lane_tiles(b: int) -> int:
+    """Fewest tiles of edge ``b`` whose sources fill whole 128-lane
+    columns; plans pad their tile slots to a multiple of it."""
+    return LANES // math.gcd(b, LANES)
 
 
-def _bsr_spmv_kernel(nnz_ref, cols_ref, vals_ref, x_ref, y_ref, *,
-                     semiring: str, bk: int, rows_per_step: int):
-    r, kc = pl.program_id(0), pl.program_id(1)
+def _chunk_tiles(bk: int, b: int, k: int) -> int:
+    """Tiles per grid chunk: ``bk`` rounded up to whole 128-lane
+    columns, or all ``k`` tiles when that is no more.  ``bk`` is a
+    tiling knob, so rounding changes no value."""
+    m = lane_tiles(b)
+    c = -(-bk // m) * m
+    return k if c >= k else c
+
+
+def _combine(semiring: str, b: int, base, vals, xs, nnz):
+    """One chunk of NALE MACs for a row group: (G, B, C) tile values ⊗
+    (G, C) gathered sources, ⊕-reduced over the chunk's lanes -> (G, B).
+    Lanes of tiles at or past a row's ``nnz`` (G, 1) — padding, or the
+    ragged last chunk read past K — are masked to the ⊕-identity."""
+    lane = jax.lax.broadcasted_iota(jnp.int32, (1, xs.shape[1]), 1)
+    live = (lane < (nnz - base) * b)[:, None, :]
+    w = jnp.where(live, vals, _init_val(semiring))
+    xs = xs[:, None, :]
+    if semiring == "plus_times":
+        return jnp.sum(w * xs, axis=2)
+    if semiring == "min_plus":
+        return jnp.min(w + xs, axis=2)
+    if semiring == "max_min":
+        return jnp.max(jnp.minimum(w, xs), axis=2)
+    if semiring == "min_select":
+        return jnp.min(jnp.where(jnp.isfinite(w), xs, jnp.inf), axis=2)
+    raise ValueError(semiring)
+
+
+def _last_chunk(n, ct: int):
+    """Index of the last chunk holding a live tile (0 when none)."""
+    return jnp.maximum((n + ct - 1) // ct - 1, 0)
+
+
+def _operands(block_vals, block_cols, block_nnz, x, rows: int, bk: int):
+    """Shared wrapper prologue: chunk geometry plus the small operands
+    padded to whole row groups and chunks.  ``block_vals`` stays as it
+    is — the ragged edge blocks read past it are masked by ``nnz``."""
+    r, b, kb = block_vals.shape
+    k = kb // b
+    ct = _chunk_tiles(bk, b, k)
+    nk = pl.cdiv(k, ct)
+    rp = pl.cdiv(r, rows) * rows
+    cols = jnp.pad(block_cols, ((0, rp - r), (0, nk * ct - k)))
+    xs = x.astype(jnp.float32)[cols].reshape(rp, nk * ct * b)
+    nnz = jnp.pad(block_nnz.astype(jnp.int32), (0, rp - r))
+    return b, ct, nk, rp, xs, nnz
+
+
+def _bsr_spmv_kernel(snnz_ref, vals_ref, xs_ref, nnz_ref, y_ref, *,
+                     semiring: str, b: int, ct: int):
+    i, kc = pl.program_id(0), pl.program_id(1)
 
     @pl.when(kc == 0)
     def _():
-        y_ref[...] = jnp.full_like(y_ref, _init_val(semiring))
+        y_ref[...] = jnp.full(y_ref.shape, _init_val(semiring), jnp.float32)
 
-    base = kc * bk
-    for rr in range(rows_per_step):
-        # Self-timed bound: only true tiles are combined.  ``nnz`` comes
-        # from a blocked spec so the scalar is already in SMEM-like storage.
-        nnz = nnz_ref[rr]
-        valid = jnp.clip(nnz - base, 0, bk)
+    base = kc * ct
 
-        @pl.when(valid > 0)
-        def _(rr=rr, nnz=nnz):
-            part = _gather_combine(semiring, bk, nnz, base, vals_ref[rr],
-                                   cols_ref[rr], x_ref)
-            y_ref[rr, :] = _acc(semiring, y_ref[rr, :], part)
+    @pl.when(snnz_ref[i] > base)   # self-timed bound of the whole step
+    def _():
+        part = _combine(semiring, b, base, vals_ref[...], xs_ref[...],
+                        nnz_ref[...])
+        y_ref[...] = _acc(semiring, y_ref[...], part)
 
 
 @functools.partial(jax.jit, static_argnames=(
@@ -113,42 +139,38 @@ def bsr_spmv(block_vals: jnp.ndarray, block_cols: jnp.ndarray,
     """Pallas block-sparse semiring SpMV.  See module docstring for layout.
 
     ``rows_per_step`` coarsens the grid: each step stages (and relaxes)
-    that many row-blocks, trading grid-step overhead for VMEM residency.
+    that many 8-row-block groups, trading grid-step overhead for VMEM
+    residency.  ``bk`` is rounded by :func:`_chunk_tiles`.
     """
-    r, k, b, _ = block_vals.shape
-    rs = max(int(rows_per_step), 1)
-    if k % bk:
-        pad = bk - k % bk
-        block_vals = jnp.pad(block_vals, ((0, 0), (0, pad), (0, 0), (0, 0)),
-                             constant_values=_init_val(semiring))
-        block_cols = jnp.pad(block_cols, ((0, 0), (0, pad)))
-        k += pad
-    r_out = r
-    if r % rs:
-        pad_r = rs - r % rs
-        block_vals = jnp.pad(block_vals, ((0, pad_r),) + ((0, 0),) * 3,
-                             constant_values=_init_val(semiring))
-        block_cols = jnp.pad(block_cols, ((0, pad_r), (0, 0)))
-        block_nnz = jnp.pad(block_nnz, (0, pad_r))  # nnz=0: never combined
-        r += pad_r
-    c = x.shape[0]
-    grid = (r // rs, k // bk)
-    y = pl.pallas_call(
-        functools.partial(_bsr_spmv_kernel, semiring=semiring, bk=bk,
-                          rows_per_step=rs),
-        grid=grid,
+    r = block_vals.shape[0]
+    rows = SUBLANES * max(int(rows_per_step), 1)
+    b, ct, nk, rp, xs, nnz = _operands(block_vals, block_cols, block_nnz,
+                                       x, rows, bk)
+    step_nnz = jnp.max(nnz.reshape(rp // rows, rows), axis=1)
+
+    def tiles(i, kc, snnz):
+        return (i, 0, jnp.minimum(kc, _last_chunk(snnz[i], ct)))
+
+    def srcs(*ix):   # the tile block's row group and chunk
+        g, _, c = tiles(*ix)
+        return g, c
+
+    grid_spec = pltpu.PrefetchScalarGridSpec(
+        num_scalar_prefetch=1,
+        grid=(rp // rows, nk),
         in_specs=[
-            pl.BlockSpec((rs,), lambda r, kc: (r,)),                    # nnz
-            pl.BlockSpec((rs, bk), lambda r, kc: (r, kc)),              # cols
-            pl.BlockSpec((rs, bk, b, b), lambda r, kc: (r, kc, 0, 0)),  # vals
-            pl.BlockSpec((c, b), lambda r, kc: (0, 0)),                 # x
+            pl.BlockSpec((rows, b, ct * b), tiles),
+            pl.BlockSpec((rows, ct * b), srcs),
+            pl.BlockSpec((rows, 1), lambda i, kc, snnz: (i, 0)),
         ],
-        out_specs=pl.BlockSpec((rs, b), lambda r, kc: (r, 0)),
-        out_shape=jax.ShapeDtypeStruct((r, b), jnp.float32),
+        out_specs=pl.BlockSpec((rows, b), lambda i, kc, snnz: (i, 0)))
+    y = pl.pallas_call(
+        functools.partial(_bsr_spmv_kernel, semiring=semiring, b=b, ct=ct),
+        grid_spec=grid_spec,
+        out_shape=jax.ShapeDtypeStruct((rp, b), jnp.float32),
         interpret=interpret,
-    )(block_nnz, block_cols, block_vals.astype(jnp.float32),
-      x.astype(jnp.float32))
-    return y[:r_out] if r_out != r else y
+    )(step_nnz, block_vals.astype(jnp.float32), xs, nnz[:, None])
+    return y[:r]
 
 
 # ---------------------------------------------------------------------------
@@ -158,18 +180,21 @@ def bsr_spmv(block_vals: jnp.ndarray, block_cols: jnp.ndarray,
 # One kernel per sweep instead of SpMV + separate XLA apply/mask/reduce
 # ops.  Active-tile skipping: the caller passes the active row-block mask
 # (rows with at least one live tile reading a changed source block); the
-# wrapper compacts it into an index list prefetched as scalars, and the
-# grid walks ONLY those rows — the paper's self-timed "empty FIFO slots
-# cost nothing" at row-block granularity.  Grid steps beyond the active
-# count are clamped onto the last active row (same block index ⇒ Mosaic
-# re-fetches nothing) and fully predicated off with ``pl.when``.
+# wrapper compacts the 8-row groups holding an active row into an index
+# list prefetched as scalars, and the grid walks ONLY those groups — the
+# paper's self-timed "empty FIFO slots cost nothing" at row-group
+# granularity.  Grid steps beyond the active count are clamped onto the
+# last active group's last block (same block index ⇒ Mosaic re-fetches
+# nothing) and fully predicated off with ``pl.when``.
 #
 # In-place frontier semantics: the output x aliases a *copy* of the input
-# row values, so rows absent from the active list pass through untouched,
-# while the kernel reads old values from the separate, unaliased full-x
-# operand — exact Jacobi, bit-identical to the unfused path (rows whose
-# inputs didn't change would recompute the same value anyway; idempotent
-# ⊕ covers self-value reads).
+# row values, so groups absent from the active list pass through
+# untouched, and inside a visited group the apply mask is ``valid & act``
+# — inactive rows keep their old values and report no change.  The
+# kernel reads old values from the separate, unaliased gathered operand —
+# exact Jacobi, bit-identical to the unfused path.  Flags travel as
+# int32, and every visited group writes all of its outputs, so no output
+# block is read before it is written.
 
 # the update rules below mirror core/engine._apply op-for-op (same jnp
 # primitives ⇒ same lowering ⇒ bit-identical results); they live here
@@ -213,46 +238,52 @@ def _apply_rows(apply_kind: str, semiring: str, y, xg, vg, damping, inv_n,
     return x_new, imp
 
 
-def _fused_kernel(na_ref, al_ref, nnz_ref, cols_ref, vals_ref, x_ref,
-                  xg_ref, valid_ref, par_ref, xa_ref, ch0_ref,
+def _fused_kernel(na_ref, al_ref, gnnz_ref, par_ref, vals_ref, xs_ref,
+                  nnz_ref, xg_ref, vg_ref, xa_ref, ch0_ref,
                   xo_ref, cho_ref, conv_ref, *,
-                  semiring: str, apply_kind: str, bk: int, nk: int):
+                  semiring: str, apply_kind: str, b: int, ct: int, nk: int):
     i, kc = pl.program_id(0), pl.program_id(1)
     del xa_ref, ch0_ref  # aliased output bases; never read in-kernel
+    na = na_ref[0]
 
     @pl.when((i == 0) & (kc == 0))
     def _():
-        conv_ref[0] = False
+        conv_ref[...] = jnp.zeros(conv_ref.shape, jnp.int32)
 
-    live_step = i < na_ref[0]
+    @pl.when(na == 0)
+    def _():
+        # nothing active: every step sits on one group, which must leave
+        # the kernel exactly as it came in
+        xo_ref[...] = xg_ref[...]
+        cho_ref[...] = jnp.zeros(cho_ref.shape, jnp.int32)
+
+    live_step = i < na
 
     # accumulate the ⊕-reduction in the aliased x-out block; the old row
     # values stay readable in the unaliased xg operand until the apply
     @pl.when(live_step & (kc == 0))
     def _():
-        xo_ref[0, :] = jnp.full_like(xo_ref[0, :], _init_val(semiring))
+        xo_ref[...] = jnp.full(xo_ref.shape, _init_val(semiring),
+                               jnp.float32)
 
-    nnz = nnz_ref[0]
-    base = kc * bk
-    valid_n = jnp.clip(nnz - base, 0, bk)
+    base = kc * ct
 
-    @pl.when(live_step & (valid_n > 0))
+    @pl.when(live_step & (gnnz_ref[al_ref[i]] > base))
     def _():
-        part = _gather_combine(semiring, bk, nnz, base, vals_ref[0],
-                               cols_ref[0], x_ref)
-        xo_ref[0, :] = _acc(semiring, xo_ref[0, :], part)
+        part = _combine(semiring, b, base, vals_ref[...], xs_ref[...],
+                        nnz_ref[...])
+        xo_ref[...] = _acc(semiring, xo_ref[...], part)
 
     @pl.when(live_step & (kc == nk - 1))
     def _():
-        y = xo_ref[0, :]
-        xg = xg_ref[0, :]
-        vg = valid_ref[0, :]
-        x_new, imp = _apply_rows(apply_kind, semiring, y, xg, vg,
+        x_new, imp = _apply_rows(apply_kind, semiring, xo_ref[...],
+                                 xg_ref[...], vg_ref[...] != 0,
                                  par_ref[0], par_ref[2], par_ref[1])
-        xo_ref[0, :] = x_new
-        imp_any = jnp.any(imp)
-        cho_ref[0] = cho_ref[0] | imp_any
-        conv_ref[0] = conv_ref[0] | imp_any
+        xo_ref[...] = x_new
+        ch = jnp.max(imp.astype(jnp.int32), axis=1, keepdims=True)
+        cho_ref[...] = ch
+        conv_ref[...] = jnp.maximum(conv_ref[...],
+                                    jnp.max(ch, axis=0, keepdims=True))
 
 
 @functools.partial(jax.jit, static_argnames=(
@@ -266,7 +297,7 @@ def bsr_spmv_fused(block_vals: jnp.ndarray, block_cols: jnp.ndarray,
     """One fused frontier-masked sweep over the active row-blocks.
 
     Args:
-      block_vals/block_cols/block_nnz: (R, K, B, B)/(R, K)/(R,) BSR rows.
+      block_vals/block_cols/block_nnz: (R, B, K*B)/(R, K)/(R,) BSR rows.
       x: (C, B) full source-node values (read-only, previous sweep).
       xg: (R, B) current values of THESE rows (``x`` itself for the
         whole-graph sync engine; the group slice for the async engine).
@@ -279,61 +310,72 @@ def bsr_spmv_fused(block_vals: jnp.ndarray, block_cols: jnp.ndarray,
       changed (R,) bool — rows the apply rule improved (next frontier);
       improved_any () bool — fused convergence flag (``changed.any()``).
     """
-    r, k, b, _ = block_vals.shape
-    if k % bk:
-        pad = bk - k % bk
-        block_vals = jnp.pad(block_vals, ((0, 0), (0, pad), (0, 0), (0, 0)),
-                             constant_values=_init_val(semiring))
-        block_cols = jnp.pad(block_cols, ((0, 0), (0, pad)))
-        k += pad
-    c = x.shape[0]
-    nk = k // bk
+    r = block_vals.shape[0]
+    g = SUBLANES
+    b, ct, nk, rp, xs, nnz = _operands(block_vals, block_cols, block_nnz,
+                                       x, g, bk)
+    ng = rp // g
+    act = jnp.pad(act_rows.astype(bool), (0, rp - r))
+    pad_rows = ((0, rp - r), (0, 0))
+    xg = jnp.pad(xg.astype(jnp.float32), pad_rows)
+    vg = jnp.pad(valid.astype(bool), pad_rows) & act[:, None]
 
-    # compact active list: active rows first (stable ⇒ deterministic),
-    # tail steps clamped onto the last active row and predicated off
-    act_rows = act_rows.astype(bool)
-    order = jnp.argsort(~act_rows, stable=True).astype(jnp.int32)
-    na = jnp.sum(act_rows).astype(jnp.int32)
-    idx = jnp.minimum(jnp.arange(r, dtype=jnp.int32),
+    # compact active group list: active groups first (stable ⇒
+    # deterministic), tail steps clamped onto the last active group and
+    # predicated off; a group's tile bound counts its active rows only
+    act_g = jnp.any(act.reshape(ng, g), axis=1)
+    group_nnz = jnp.max(jnp.where(act, nnz, 0).reshape(ng, g), axis=1)
+    order = jnp.argsort(~act_g, stable=True).astype(jnp.int32)
+    na = jnp.sum(act_g).astype(jnp.int32)
+    idx = jnp.minimum(jnp.arange(ng, dtype=jnp.int32),
                       jnp.maximum(na - 1, 0))
     active_list = order[idx]
     params = jnp.stack([jnp.float32(damping), jnp.float32(tol),
                         jnp.float32(inv_n)])
 
-    xg = xg.astype(jnp.float32)
+    def tiles(i, kc, na, al, gnnz):
+        # tail steps (i >= na) repeat the last live step's block
+        kc = jnp.where(i < na[0], kc, nk - 1)
+        return (al[i], 0, jnp.minimum(kc, _last_chunk(gnnz[al[i]], ct)))
+
+    def srcs(*ix):   # the tile block's row group and chunk
+        g, _, c = tiles(*ix)
+        return g, c
+
+    def rows(i, kc, na, al, gnnz):
+        return (al[i], 0)
+
     grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=2,
-        grid=(r, nk),
+        num_scalar_prefetch=3,
+        grid=(ng, nk),
         in_specs=[
-            pl.BlockSpec((1,), lambda i, kc, na, al: (al[i],)),    # nnz
-            pl.BlockSpec((1, bk), lambda i, kc, na, al: (al[i], kc)),
-            pl.BlockSpec((1, bk, b, b),
-                         lambda i, kc, na, al: (al[i], kc, 0, 0)),  # vals
-            pl.BlockSpec((c, b), lambda i, kc, na, al: (0, 0)),     # x
-            pl.BlockSpec((1, b), lambda i, kc, na, al: (al[i], 0)),  # xg
-            pl.BlockSpec((1, b), lambda i, kc, na, al: (al[i], 0)),  # valid
-            pl.BlockSpec((3,), lambda i, kc, na, al: (0,)),         # params
-            pl.BlockSpec((1, b), lambda i, kc, na, al: (al[i], 0)),  # x alias
-            pl.BlockSpec((1,), lambda i, kc, na, al: (al[i],)),     # ch alias
+            pl.BlockSpec(memory_space=pltpu.SMEM),        # params
+            pl.BlockSpec((g, b, ct * b), tiles),          # vals
+            pl.BlockSpec((g, ct * b), srcs),              # gathered x
+            pl.BlockSpec((g, 1), rows),                   # nnz
+            pl.BlockSpec((g, b), rows),                   # xg
+            pl.BlockSpec((g, b), rows),                   # valid & act
+            pl.BlockSpec((g, b), rows),                   # x alias
+            pl.BlockSpec((g, 1), rows),                   # ch alias
         ],
         out_specs=[
-            pl.BlockSpec((1, b), lambda i, kc, na, al: (al[i], 0)),  # x_new
-            pl.BlockSpec((1,), lambda i, kc, na, al: (al[i],)),     # changed
-            pl.BlockSpec((1,), lambda i, kc, na, al: (0,)),         # conv
+            pl.BlockSpec((g, b), rows),                   # x_new
+            pl.BlockSpec((g, 1), rows),                   # changed
+            pl.BlockSpec((1, 1), lambda i, kc, na, al, gnnz: (0, 0)),
         ])
     x_new, changed, conv = pl.pallas_call(
         functools.partial(_fused_kernel, semiring=semiring,
-                          apply_kind=apply_kind, bk=bk, nk=nk),
+                          apply_kind=apply_kind, b=b, ct=ct, nk=nk),
         grid_spec=grid_spec,
-        out_shape=[jax.ShapeDtypeStruct((r, b), jnp.float32),
-                   jax.ShapeDtypeStruct((r,), jnp.bool_),
-                   jax.ShapeDtypeStruct((1,), jnp.bool_)],
-        # operand indices COUNT the scalar-prefetch operands (na, al):
-        # 9 = the xg copy aliased onto x_new, 10 = the zero changed-bits
+        out_shape=[jax.ShapeDtypeStruct((rp, b), jnp.float32),
+                   jax.ShapeDtypeStruct((rp, 1), jnp.int32),
+                   jax.ShapeDtypeStruct((1, 1), jnp.int32)],
+        # operand indices COUNT the scalar-prefetch operands (na, al,
+        # gnnz): 9 = the xg copy aliased onto x_new, 10 = the zero
+        # changed flags
         input_output_aliases={9: 0, 10: 1},
         interpret=interpret,
-    )(jnp.reshape(na, (1,)), active_list,
-      block_nnz, block_cols, block_vals.astype(jnp.float32),
-      x.astype(jnp.float32), xg, valid, params,
-      xg, jnp.zeros((r,), dtype=jnp.bool_))
-    return x_new, changed, conv[0]
+    )(jnp.reshape(na, (1,)), active_list, group_nnz, params,
+      block_vals.astype(jnp.float32), xs, nnz[:, None], xg,
+      vg.astype(jnp.int32), xg, jnp.zeros((rp, 1), jnp.int32))
+    return x_new[:r], changed[:r, 0] != 0, conv[0, 0] != 0

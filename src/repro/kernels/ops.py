@@ -1,6 +1,6 @@
 """Public jit'd kernel entry points behind one ``select_kernel`` registry.
 
-Pallas-Mosaic lowers only on TPU; this container is CPU, so:
+Pallas-Mosaic lowers only on TPU, so:
   * default path (``KernelSpec(impl="ref")``) is the pure-jnp oracle,
     which XLA fuses — this is also what the multi-pod dry-run lowers
     (Pallas calls cannot be SPMD-partitioned across a 512-device host
@@ -53,22 +53,16 @@ from .spec import DEFAULT_BLOCK_SIZE, KernelSpec, as_kernel_spec
 
 
 def resolve_platform(platform: Optional[str] = None) -> str:
-    if platform is not None:
-        return platform
-    try:
-        return jax.default_backend()
-    except Exception:  # pragma: no cover
-        return "cpu"
+    """The platform kernels are built for: ``platform`` when given, else
+    JAX's default backend.  A backend that fails to initialize raises —
+    it is never reported as the CPU."""
+    return platform if platform is not None else jax.default_backend()
 
 
 def use_interpret(platform: Optional[str] = None) -> bool:
-    """Mosaic lowers only on TPU; every other backend (this CPU
-    container, GPU) runs Pallas kernels in interpret mode."""
+    """Mosaic lowers only on TPU; every other backend (CPU, GPU) runs
+    Pallas kernels in interpret mode."""
     return resolve_platform(platform) != "tpu"
-
-
-def _on_tpu() -> bool:  # legacy spelling, kept for external callers
-    return not use_interpret()
 
 
 # ---------------------------------------------------------------------------

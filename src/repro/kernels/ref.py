@@ -17,10 +17,11 @@ import jax.numpy as jnp
 
 def bsr_spmv_ref(block_vals: jnp.ndarray, block_cols: jnp.ndarray,
                  x: jnp.ndarray, semiring: str = "plus_times") -> jnp.ndarray:
-    """y[r*b+i] = ⊕_{k,j} vals[r,k,i,j] ⊗ x[cols[r,k]*b+j].
+    """y[r*b+i] = ⊕_{k,j} vals[r,i,k*b+j] ⊗ x[cols[r,k]*b+j].
 
     Args:
-      block_vals: (R, K, B, B) tile values (padded with ⊕-identity).
+      block_vals: (R, B, K*B) destination-major tile values (padded with
+        the ⊕-identity; see ``core.graph.BsrGraph``).
       block_cols: (R, K) int32 col-block ids (padding points anywhere; the
         padded tile's values are ⊕-identities so the result is unaffected).
       x: (C, B) input vector in block layout.
@@ -32,27 +33,27 @@ def bsr_spmv_ref(block_vals: jnp.ndarray, block_cols: jnp.ndarray,
     Returns:
       y: (R, B).
     """
-    xs = x[block_cols]  # (R, K, B)
+    r = block_vals.shape[0]
+    xs = x[block_cols].reshape(r, 1, -1)  # (R, 1, K*B), sources on lanes
     if semiring == "plus_times":
-        return jnp.einsum("rkij,rkj->ri", block_vals, xs)
+        # full f32 products: TPU's default matmul precision is bf16
+        return jnp.einsum("rij,rj->ri", block_vals, xs[:, 0],
+                          precision=jax.lax.Precision.HIGHEST)
     if semiring == "min_plus":
-        t = block_vals + xs[:, :, None, :]          # (R, K, B, B)
-        return jnp.min(t, axis=(1, 3))
+        return jnp.min(block_vals + xs, axis=2)
     if semiring == "max_min":
-        t = jnp.minimum(block_vals, xs[:, :, None, :])
-        return jnp.max(t, axis=(1, 3))
+        return jnp.max(jnp.minimum(block_vals, xs), axis=2)
     if semiring == "min_select":
         # mul(w, x) = x when an edge exists; absent edges hold +inf weight.
-        t = jnp.where(jnp.isfinite(block_vals), xs[:, :, None, :], jnp.inf)
-        return jnp.min(t, axis=(1, 3))
+        t = jnp.where(jnp.isfinite(block_vals), xs, jnp.inf)
+        return jnp.min(t, axis=2)
     # registered custom semiring: generic ⊗-then-⊕ over the tile and
     # source axes.  Imported lazily — this runs post-import (kernels/
     # must not import core/ at module load; core.__init__ → engine →
     # kernels.ops would cycle).
     from ..core import semiring as _sr
     ring = _sr.get(semiring)
-    t = ring.mul(block_vals, xs[:, :, None, :])     # (R, K, B, B)
-    return ring.reduce(t, axis=(1, 3))
+    return ring.reduce(ring.mul(block_vals, xs), axis=2)
 
 
 # ---------------------------------------------------------------------------

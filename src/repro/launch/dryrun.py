@@ -91,8 +91,6 @@ def parse_collectives(hlo_text: str) -> Dict[str, float]:
 
 def _cost(compiled) -> Dict[str, float]:
     c = compiled.cost_analysis() or {}
-    if isinstance(c, (list, tuple)):  # jax 0.4.x: one dict per partition
-        c = c[0] if c else {}
     return {"flops": float(c.get("flops", 0.0)),
             "bytes": float(c.get("bytes accessed", 0.0))}
 

@@ -9,12 +9,8 @@ import jax
 
 
 def _mesh(shape, axes):
-    # jax.sharding.AxisType landed after 0.4.x; Auto is the default there
-    axis_type = getattr(jax.sharding, "AxisType", None)
-    if axis_type is None:
-        return jax.make_mesh(shape, axes)
-    return jax.make_mesh(shape, axes,
-                         axis_types=(axis_type.Auto,) * len(axes))
+    auto = jax.sharding.AxisType.Auto
+    return jax.make_mesh(shape, axes, axis_types=(auto,) * len(axes))
 
 
 def make_production_mesh(*, multi_pod: bool = False):

@@ -3,10 +3,13 @@
 Terms per (arch × shape), single-pod 16×16 mesh, from the per-device
 composed cost analysis (see dryrun.py for the while-body composition):
 
-  compute_s    = HLO_FLOPs_per_device / 197e12        (bf16 peak / chip)
-  memory_s     = HLO_bytes_per_device / 819e9         (HBM BW / chip)
-  collective_s = collective_bytes_per_device / 50e9   (1 ICI link, worst
+  compute_s    = HLO_FLOPs_per_device / peak FLOP/s   (bf16 peak / chip)
+  memory_s     = HLO_bytes_per_device / HBM bytes/s
+  collective_s = collective_bytes_per_device / ICI bytes/s (1 link, worst
                  case serialization; v5e has 4 links → best case ÷4)
+
+with the peaks of the pod's chip from ``PEAKS`` (the dry-run describes a
+TPU v5e pod slice).
 
 The dominant term is the bottleneck; roofline fraction for the dominant
 term = useful/attained:  MODEL_FLOPS/(chips·peak·T_dom) when compute-
@@ -22,30 +25,55 @@ import argparse
 import glob
 import json
 import os
-from typing import Dict, List, Optional
+from typing import Dict, List, NamedTuple, Optional
 
 from ..configs.base import get_config
 from . import analytic
 from .specs import SHAPES
 
-PEAK_FLOPS = 197e12     # bf16 / chip (TPU v5e class)
-HBM_BW = 819e9          # bytes/s / chip
-ICI_BW = 50e9           # bytes/s / link
+
+
+class Peaks(NamedTuple):
+    flops: float    # bf16 FLOP/s per chip
+    hbm_bw: float   # HBM bytes/s per chip
+    ici_bw: float   # ICI bytes/s per link
+
+
+# Published per-chip peaks keyed by ``jax.Device.device_kind``.  Source:
+# Google Cloud TPU documentation, "TPU v5e" system architecture: 197
+# TFLOP/s bf16, 16 GiB HBM at 819 GB/s, 1,600 Gbit/s of inter-chip
+# interconnect per chip over 4 links (50 GB/s per link).
+PEAKS: Dict[str, Peaks] = {
+    "TPU v5 lite": Peaks(flops=197e12, hbm_bw=819e9, ici_bw=50e9),
+}
+DRYRUN_DEVICE = "TPU v5 lite"   # the chip of the dry-run's pod slice
 CHIPS = 256
 
 
-def kernel_roofline(flops: float, hbm_bytes: float,
+def peaks(device_kind: str) -> Peaks:
+    """Peaks of one chip; a device missing from ``PEAKS`` is an error,
+    never a default."""
+    try:
+        return PEAKS[device_kind]
+    except KeyError:
+        raise KeyError(
+            f"no published peaks for device kind {device_kind!r}; "
+            f"known: {sorted(PEAKS)}") from None
+
+
+def kernel_roofline(flops: float, hbm_bytes: float, device_kind: str,
                     ici_bytes: float = 0.0) -> Dict:
     """Single-chip roofline for one kernel invocation (no dry-run dump):
     seconds per term, the dominant bottleneck, and the modeled runtime
-    assuming perfect compute/memory overlap.  The kernel autotuner
-    (kernels/autotune.py) validates its *measured* winner against this
-    model — agreement means the measurement is believable, disagreement
-    is recorded (measured always wins; the model can't see interpret
-    mode or VMEM effects)."""
-    t_compute = flops / PEAK_FLOPS
-    t_memory = hbm_bytes / HBM_BW
-    t_coll = ici_bytes / ICI_BW
+    assuming perfect compute/memory overlap, on the peaks of
+    ``device_kind``.  The kernel autotuner (kernels/autotune.py)
+    validates its *measured* winner against this model — agreement
+    means the measurement is believable, disagreement is recorded
+    (measured always wins; the model can't see VMEM effects)."""
+    pk = peaks(device_kind)
+    t_compute = flops / pk.flops
+    t_memory = hbm_bytes / pk.hbm_bw
+    t_coll = ici_bytes / pk.ici_bw
     terms = {"compute": t_compute, "memory": t_memory,
              "collective": t_coll}
     dominant = max(terms, key=terms.get)
@@ -73,9 +101,10 @@ def roofline_row(cell: Dict) -> Optional[Dict]:
         + analytic.prefill_attention_correction(cfg0, cell["shape"])
     bytes_dev = comp["cost"]["bytes"]
     coll_dev = comp["collectives"].get("total_bytes", 0.0)
-    t_compute = flops_dev / PEAK_FLOPS
-    t_memory = bytes_dev / HBM_BW
-    t_coll = coll_dev / ICI_BW
+    pk = peaks(DRYRUN_DEVICE)
+    t_compute = flops_dev / pk.flops
+    t_memory = bytes_dev / pk.hbm_bw
+    t_coll = coll_dev / pk.ici_bw
     terms = {"compute": t_compute, "memory": t_memory,
              "collective": t_coll}
     dominant = max(terms, key=terms.get)
@@ -86,7 +115,7 @@ def roofline_row(cell: Dict) -> Optional[Dict]:
     useful = an["model_flops"] / hlo_total if hlo_total else 0.0
     # attained fraction of the dominant roof if perfectly overlapped
     t_dom = terms[dominant]
-    mfu_bound = an["model_flops"] / (CHIPS * PEAK_FLOPS * t_dom) \
+    mfu_bound = an["model_flops"] / (CHIPS * pk.flops * t_dom) \
         if t_dom else 0.0
     return {
         "arch": cell["arch"], "shape": cell["shape"],

@@ -14,11 +14,6 @@ from typing import Optional, Tuple
 import jax
 import jax.numpy as jnp
 
-# jax.shard_map landed in 0.5.x; this container ships 0.4.x
-_shard_map = getattr(jax, "shard_map", None)
-if _shard_map is None:  # pragma: no cover - version dependent
-    from jax.experimental.shard_map import shard_map as _shard_map
-
 from ..configs.base import ModelConfig
 from ..kernels import ops
 from ..sharding.rules import constrain
@@ -242,7 +237,7 @@ def _decode_attend_flash(cfg, q, k, v, pos, window, mesh):
     chunk = s_len // n_shards
 
     @functools.partial(
-        _shard_map, mesh=mesh,
+        jax.shard_map, mesh=mesh,
         in_specs=(q_spec, kv_spec, kv_spec, pos_spec),
         out_specs=q_spec)
     def attend(ql, kl, vl, posl):

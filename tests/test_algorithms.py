@@ -314,7 +314,9 @@ def test_custom_semiring_ref_kernel_fallback():
     vals = rng.random((r_, k_, b_, b_)).astype(np.float32)
     cols = rng.integers(0, c_, size=(r_, k_)).astype(np.int32)
     x = rng.random((c_, b_)).astype(np.float32)
-    y = np.asarray(bsr_spmv_ref(jnp.asarray(vals), jnp.asarray(cols),
+    # destination-major tile image: [r, i, k*b+j] = vals[r, k, i, j]
+    image = vals.transpose(0, 2, 1, 3).reshape(r_, b_, k_ * b_)
+    y = np.asarray(bsr_spmv_ref(jnp.asarray(image), jnp.asarray(cols),
                                 jnp.asarray(x), semiring=ring.name))
     want = (vals * x[cols][:, :, None, :]).max(axis=(1, 3))
     np.testing.assert_allclose(y, want, rtol=1e-6)

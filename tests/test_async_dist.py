@@ -198,6 +198,7 @@ def test_service_wave_uses_async_engine():
 
 _SUBPROCESS_8DEV_ASYNC = r"""
 import os
+os.environ["JAX_PLATFORMS"] = "cpu"  # 8 fake host devices; never a chip
 os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=8"
 import numpy as np
 from repro.core import async_dist as AD, engine as E, graph as G, \

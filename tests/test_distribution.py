@@ -164,6 +164,7 @@ def test_batched_distributed_parity_across_factorizations(
 
 _SUBPROCESS_8DEV = r"""
 import os
+os.environ["JAX_PLATFORMS"] = "cpu"  # 8 fake host devices; never a chip
 os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=8"
 import numpy as np
 from repro.core import algorithms as A, engine as E, graph as G, \

@@ -86,8 +86,8 @@ def test_bsr_padding_is_noop(rng):
                           jnp.asarray(bsr.block_nnz), jnp.asarray(x),
                           semiring=name, impl="ref")
         # append 2 extra all-padding tile slots per row
-        pad_v = np.full((bsr.r, 2, 8, 8), z, np.float32)
-        vals = np.concatenate([bsr.block_vals, pad_v], axis=1)
+        pad_v = np.full((bsr.r, 8, 2 * 8), z, np.float32)
+        vals = np.concatenate([bsr.block_vals, pad_v], axis=2)
         cols = np.concatenate([bsr.block_cols,
                                np.zeros((bsr.r, 2), np.int32)], axis=1)
         y1 = ops.bsr_spmv(jnp.asarray(vals), jnp.asarray(cols),
@@ -196,9 +196,9 @@ def test_fused_respects_nnz_bound(rng):
     g = G.rmat(60, 240, seed=4)
     bsr = G.to_bsr(g, b=8, pad_value=np.inf)  # min_plus
     vals = bsr.block_vals.copy()
-    lane = np.arange(bsr.k_max)[None, :]
+    lane = np.arange(bsr.k_max * bsr.b)[None, :] // bsr.b
     trash = lane >= bsr.block_nnz[:, None]
-    vals[np.broadcast_to(trash[:, :, None, None], vals.shape)] = -123.0
+    vals[np.broadcast_to(trash[:, None, :], vals.shape)] = -123.0
     x = rng.random((bsr.r, bsr.b)).astype(np.float32)
     valid = np.ones((bsr.r, bsr.b), bool)
     act = np.ones(bsr.r, bool)
@@ -215,9 +215,9 @@ def test_pallas_respects_nnz_bound(rng):
     g = G.rmat(60, 240, seed=4)
     bsr = G.to_bsr(g, b=8, pad_value=np.inf)  # min_plus
     vals = bsr.block_vals.copy()
-    lane = np.arange(bsr.k_max)[None, :]
+    lane = np.arange(bsr.k_max * bsr.b)[None, :] // bsr.b
     trash = lane >= bsr.block_nnz[:, None]
-    vals[np.broadcast_to(trash[:, :, None, None], vals.shape)] = -123.0
+    vals[np.broadcast_to(trash[:, None, :], vals.shape)] = -123.0
     x = rng.random((bsr.r, bsr.b)).astype(np.float32)
     y_pal = ops.bsr_spmv(jnp.asarray(vals), jnp.asarray(bsr.block_cols),
                          jnp.asarray(bsr.block_nnz), jnp.asarray(x),

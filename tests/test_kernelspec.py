@@ -15,6 +15,7 @@ from repro.core import graph as G
 from repro.kernels import ops
 from repro.kernels import autotune as at
 from repro.kernels.spec import KernelSpec, as_kernel_spec
+from repro.launch.roofline import PEAKS
 
 FUSED = KernelSpec(impl="pallas", fuse_frontier=True)
 
@@ -125,7 +126,9 @@ def test_policy_but_rederives_the_other_spelling():
 @pytest.mark.parametrize("mode", ["sync", "async"])
 @pytest.mark.parametrize("algo", ["sssp", "bfs", "reachability", "cc"])
 def test_engine_fused_bit_identical(proc, mode, algo, rng):
-    pol = api.ExecutionPolicy(mode=mode, max_sweeps=10_000)
+    # degrade=False: a kernel failure must fail the test, not re-run
+    # the query on ref and compare ref with ref
+    pol = api.ExecutionPolicy(mode=mode, max_sweeps=10_000, degrade=False)
     polf = pol.but(kernel=FUSED)
     run = {"sssp": lambda pl: proc.sssp(3, policy=pl),
            "bfs": lambda pl: proc.bfs(3, policy=pl),
@@ -142,7 +145,7 @@ def test_engine_fused_pagerank(proc, mode):
     # plus_times accumulates in a different grouping inside the fused
     # kernel; over a full damped-iteration trajectory the drift stays
     # below the convergence tolerance but is not bitwise
-    pol = api.ExecutionPolicy(mode=mode)
+    pol = api.ExecutionPolicy(mode=mode, degrade=False)
     r0 = proc.pagerank(policy=pol)
     r1 = proc.pagerank(policy=pol.but(kernel=FUSED))
     np.testing.assert_allclose(r0.values, r1.values, atol=1e-6)
@@ -150,7 +153,8 @@ def test_engine_fused_pagerank(proc, mode):
 
 
 def test_engine_fused_batched(proc):
-    pol = api.ExecutionPolicy(mode="sync", max_sweeps=10_000)
+    pol = api.ExecutionPolicy(mode="sync", max_sweeps=10_000,
+                              degrade=False)
     r0 = proc.sssp(sources=[0, 5, 9], policy=pol)
     r1 = proc.sssp(sources=[0, 5, 9], policy=pol.but(kernel=FUSED))
     np.testing.assert_array_equal(r0.values, r1.values)
@@ -190,7 +194,9 @@ def test_autotune_deterministic(proc):
     assert (rec1["block_size"], rec1["rows_per_step"]) == (4, 2)
     assert rec1["seed"] == 0
     assert len(calls) == len(rec1["candidates"])
-    assert rec1["modeled_s"] > 0 and rec1["measured_s"] > 0
+    assert rec1["measured_s"] > 0
+    # the roofline cross-check exists only on chips with published peaks
+    assert (rec1["modeled_s"] is None) == (rec1["device_kind"] not in PEAKS)
     # pinned fields shrink the sweep
     pinned = at.autotune_spmv(
         p, KernelSpec(impl="pallas", autotune=True, block_size=8),
@@ -203,7 +209,7 @@ def test_autotune_deterministic(proc):
 def test_autotune_cached_per_plan(graph):
     proc = api.GraphProcessor(graph, b=16, num_clusters=16)
     spec = KernelSpec(impl="pallas", fuse_frontier=True, autotune=True)
-    pol = api.ExecutionPolicy(mode="sync", kernel=spec)
+    pol = api.ExecutionPolicy(mode="sync", kernel=spec, degrade=False)
     r1 = proc.sssp(3, policy=pol)
     r2 = proc.sssp(5, policy=pol)
     info = proc.cache_info()
@@ -216,7 +222,7 @@ def test_autotune_cached_per_plan(graph):
 
 def test_tunings_survive_plan_store_restart(graph, tmp_path):
     spec = KernelSpec(impl="pallas", autotune=True)
-    pol = api.ExecutionPolicy(mode="sync", kernel=spec)
+    pol = api.ExecutionPolicy(mode="sync", kernel=spec, degrade=False)
 
     svc = api.GraphService(cache_dir=str(tmp_path))
     proc = svc.register("g", graph, b=16, num_clusters=16)

@@ -1,0 +1,107 @@
+"""Compile the graph path for a TPU v5e at the full CA road-graph shapes.
+
+No chip is needed: JAX describes a v5e:2x2 topology and the TPU compiler
+compiles for it, refusing what the chip would refuse (block shapes off
+the (8, 128) tiling, VMEM overflow, programs over HBM).  Nothing runs,
+so these tests say nothing about values or times.
+
+The shapes are the smoke's plan at scale 1.0 (``chip_smoke.py``): R row-
+blocks of B=16 after 64-way clustering and K tile slots (k_max 30 padded
+to whole 128-lane columns).
+"""
+
+import os
+
+import jax
+import jax.numpy as jnp
+import numpy as np
+import pytest
+from jax.sharding import Mesh, SingleDeviceSharding
+
+from repro.core import placement
+from repro.kernels import bsr_spmv as K
+from repro.kernels import ref
+
+R, KT, B = 122688, 32, 16
+HBM = 16 << 30
+
+
+@pytest.fixture(scope="module")
+def topo():
+    os.environ.setdefault("TPU_LOG_DIR", "disabled")  # no compiler logs
+    from jax.experimental import topologies
+    from jax.experimental.compilation_cache import compilation_cache
+    try:
+        t = topologies.get_topology_desc(platform="tpu",
+                                         topology_name="v5e:2x2")
+    except Exception as e:
+        pytest.skip(f"no v5e:2x2 topology can be described here: {e}")
+    # a compile for a described chip can be written to the persistent
+    # cache but never read back here: keep the cache out of it
+    was = jax.config.jax_enable_compilation_cache
+    jax.config.update("jax_enable_compilation_cache", False)
+    compilation_cache.reset_cache()
+    yield t
+    jax.config.update("jax_enable_compilation_cache", was)
+    compilation_cache.reset_cache()
+
+
+@pytest.fixture(scope="module")
+def plan(topo):
+    one = SingleDeviceSharding(topo.devices[0])
+
+    def sds(shape, dtype=jnp.float32):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one)
+
+    return dict(vals=sds((R, B, KT * B)), cols=sds((R, KT), jnp.int32),
+                nnz=sds((R,), jnp.int32), x=sds((R, B)),
+                valid=sds((R, B), jnp.bool_), act=sds((R,), jnp.bool_),
+                scalar=sds(()))
+
+
+def _fits(compiled):
+    m = compiled.memory_analysis()
+    return m.argument_size_in_bytes + m.temp_size_in_bytes < HBM
+
+
+@pytest.mark.parametrize("semiring", ["min_plus", "plus_times"])
+def test_pallas_spmv_compiles(plan, semiring):
+    c = jax.jit(lambda v, cl, n, x: K.bsr_spmv(
+        v, cl, n, x, semiring=semiring, interpret=False)).lower(
+            plan["vals"], plan["cols"], plan["nnz"], plan["x"]).compile()
+    assert "tpu_custom_call" in c.as_text()
+    assert _fits(c)
+
+
+@pytest.mark.parametrize("semiring", ["min_plus", "plus_times"])
+def test_pallas_fused_compiles(plan, semiring):
+    apply_kind = "relax" if semiring == "min_plus" else "pagerank"
+    s = plan["scalar"]
+    c = jax.jit(lambda v, cl, n, x, ok, a, d, t, i: K.bsr_spmv_fused(
+        v, cl, n, x, x, ok, a, d, t, i, semiring=semiring,
+        apply_kind=apply_kind, interpret=False)).lower(
+            plan["vals"], plan["cols"], plan["nnz"], plan["x"],
+            plan["valid"], plan["act"], s, s, s).compile()
+    assert "tpu_custom_call" in c.as_text()
+    assert _fits(c)
+
+
+def test_ref_spmv_compiles(plan):
+    c = jax.jit(lambda v, cl, x: ref.bsr_spmv_ref(
+        v, cl, x, "min_plus")).lower(
+            plan["vals"], plan["cols"], plan["x"]).compile()
+    assert _fits(c)
+
+
+@pytest.mark.parametrize("mesh_shape", [(4, 1), (2, 2)])
+def test_distributed_sweep_compiles(topo, mesh_shape):
+    mesh = Mesh(np.asarray(topo.devices).reshape(mesh_shape),
+                ("graph", "query"))
+
+    class Plan:   # the fields lower_distributed reads
+        r_pad, k_max, b, semiring, n = R, KT, B, "min_plus", R * B
+
+    c = placement.lower_distributed(Plan, mesh, batch=8).compile()
+    text = c.as_text()
+    assert "all-gather" in text
+    assert _fits(c)
