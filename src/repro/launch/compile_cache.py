@@ -3,8 +3,8 @@
 ``JAX_COMPILATION_CACHE_DIR``, when set, is the cache: JAX reads it on
 its own and nothing here overrides it.  Otherwise the cache lives at a
 fixed path inside the checkout, ``<checkout>/.jax_cache`` (listed in
-``.gitignore``).  The path is part of each entry's key, so it never
-depends on a temp name, a process id or the time.
+``.gitignore``).  The path never depends on a temp name, a process id
+or the time, so a later run from the same checkout finds the entries.
 """
 
 from __future__ import annotations

@@ -32,7 +32,6 @@ from . import analytic
 from .specs import SHAPES
 
 
-
 class Peaks(NamedTuple):
     flops: float    # bf16 FLOP/s per chip
     hbm_bw: float   # HBM bytes/s per chip
