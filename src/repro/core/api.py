@@ -33,6 +33,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from .. import obs
 from . import engine as eng
 from .algorithms import (AlgorithmSpec, get_algorithm,  # noqa: F401
                          register_algorithm, registered_algorithms)
@@ -517,18 +518,24 @@ class GraphProcessor:
             return res
 
     def _execute(self, spec: QuerySpec, pol: ExecutionPolicy) -> Result:
-        """One engine attempt at (spec, pol) — the pre-ladder ``run``."""
-        p, key, x0f, pad, apply_kind, post = self._relaxation_setup(
-            spec, pol)
-        kern = self._kernel_for_run(p, key, pol.kernel)
+        """One engine attempt at (spec, pol) — the pre-ladder ``run``.
+
+        Timed as three spans (``repro.obs``): ``run.prep`` (plan lookup,
+        initial state built and uploaded), ``run.device`` (dispatch until
+        the sweep count is on the host) and ``run.fetch`` (device → host
+        copies, un-permute, ``post``)."""
         if spec.batched:
-            return self._run_batched(spec, pol, p, x0f, pad, apply_kind,
-                                     post, kern)
-        src = spec.sources[0] if spec.sources else None
-        x0 = p.to_blocks(x0f(src), pad)
-        x, stats, extra = self._dispatch(pol, p, x0, apply_kind, src,
-                                         kern)
-        values = post(p.from_blocks(x))
+            return self._run_batched(spec, pol)
+        with obs.span("run.prep"):
+            p, kern, x0f, pad, apply_kind, post = self._relaxation_setup(
+                spec, pol)
+            src = spec.sources[0] if spec.sources else None
+            x0 = p.to_blocks(x0f(src), pad)
+        with obs.span("run.device"):
+            x, stats, extra = self._dispatch(pol, p, x0, apply_kind, src,
+                                             kern)
+        with obs.span("run.fetch"):
+            values = post(p.from_blocks(x))
         extra = dict(extra, algo=spec.algo,
                      **({"src": src} if src is not None else {}))
         return Result(values, stats, p, extra, policy=pol, graph=self.g)
@@ -536,17 +543,18 @@ class GraphProcessor:
     # -- registry-driven plan + frontier-init descriptors ----------------
 
     def _relaxation_setup(self, spec: QuerySpec, pol: ExecutionPolicy):
-        """Returns (Prepared, PlanKey, x0_builder(src), pad, apply_kind,
-        post) — all read off the algorithm's registered
+        """Returns (Prepared, KernelSpec to run, x0_builder(src), pad,
+        apply_kind, post) — all read off the algorithm's registered
         ``AlgorithmSpec``; no per-algorithm branching here."""
         a = get_algorithm(spec.algo)
         key = self.plan_key(a.semiring, variant=a.variant, pull=a.pull,
                             normalize=a.normalize)
         p = self.prepare(a.semiring, variant=a.variant, pull=a.pull,
                          normalize=a.normalize)
+        kern = self._kernel_for_run(p, key, pol.kernel)
         pad = float(a.ring.zero) if a.pad is None else a.pad
         post = a.post if a.post is not None else (lambda v: v)
-        return p, key, (lambda src: a.init(p, src, pol)), pad, \
+        return p, kern, (lambda src: a.init(p, src, pol)), pad, \
             a.update, post
 
     def _frontier(self, p: Prepared, src: Optional[int]) -> jnp.ndarray:
@@ -591,66 +599,67 @@ class GraphProcessor:
                               "distributed")
         return x, stats, {"dist": dist}
 
-    def _run_batched(self, spec: QuerySpec, pol: ExecutionPolicy,
-                     p: Prepared, x0f, pad, apply_kind, post,
-                     kern: Optional[KernelSpec] = None) -> Result:
-        kern = kern if kern is not None else pol.kernel
+    def _run_batched(self, spec: QuerySpec,
+                     pol: ExecutionPolicy) -> Result:
         sources = list(spec.sources)
         if not sources:
             raise ValueError("batched query needs at least one source")
-        if pol.mode == "distributed":
-            if pol.query_axis == 0:
-                return self._run_batched_dist_fallback(
-                    spec, pol, p, x0f, pad, apply_kind, post, sources)
+        dist = pol.mode == "distributed"
+        if dist and pol.query_axis == 0:
+            return self._run_batched_dist_fallback(spec, pol, sources)
+        with obs.span("run.prep"):
+            p, kern, x0f, pad, apply_kind, post = self._relaxation_setup(
+                spec, pol)
+            if dist:
+                # Stack on host: the engine pads/shards the frontier
+                # itself, so a device-resident stack would round-trip
+                # pointlessly.
+                x0 = np.stack([np.asarray(p.to_blocks(x0f(s), pad))
+                               for s in sources])
+            else:
+                x0 = jnp.stack([p.to_blocks(x0f(s), pad)
+                                for s in sources])
+                ch0 = (jnp.stack([self._frontier(p, s) for s in sources])
+                       if pol.mode == "async" or kern.fuse_frontier
+                       else None)
+        extra = {"algo": spec.algo, "sources": sources}
+        kw = dict(apply_kind=apply_kind, damping=pol.damping, tol=pol.tol,
+                  max_sweeps=pol.max_sweeps)
+        with obs.span("run.device"):
+            if not dist:
+                run = (eng.run_async_batched if pol.mode == "async"
+                       else eng.run_sync_batched)
+                x, stats = run(p, x0, changed0=ch0, kernel=kern, **kw)
             # One 2-D shard_map dispatch: rows over "graph", the query
             # axis over "query" (placement.distributed_sync_run_batched).
             # Bit-identical to the per-source sequential path; `sweeps`
             # is the straggler's, work counters total the query axis.
-            # Stack on host: the engine pads/shards the frontier itself,
-            # so a device-resident stack would round-trip pointlessly.
-            x0 = np.stack([np.asarray(p.to_blocks(x0f(s), pad))
-                           for s in sources])
-            ekw = dict(apply_kind=apply_kind, damping=pol.damping,
-                       tol=pol.tol, max_sweeps=pol.max_sweeps,
-                       query_axis=pol.query_axis)
-            if pol.dist_flavor == "async":
+            elif pol.dist_flavor == "async":
                 from . import async_dist
-                x, dist = async_dist.distributed_async_run_batched(
-                    p, x0, local_sweeps=pol.local_sweeps, **ekw)
-                stats = eng.dist_run_stats(p, dist)
+                x, extra["dist"] = \
+                    async_dist.distributed_async_run_batched(
+                        p, x0, local_sweeps=pol.local_sweeps,
+                        query_axis=pol.query_axis, **kw)
+                stats = eng.dist_run_stats(p, extra["dist"])
             else:
                 from . import placement
-                x, dist = placement.distributed_sync_run_batched(
-                    p, x0, **ekw)
+                x, d = placement.distributed_sync_run_batched(
+                    p, x0, query_axis=pol.query_axis, **kw)
                 stats = eng.bsp_stats(
-                    p, dist.sweeps, dist.converged, "distributed",
-                    work_sweeps=int(dist.query_sweeps.sum()))
+                    p, d.sweeps, d.converged, "distributed",
+                    work_sweeps=int(d.query_sweeps.sum()))
+                extra["dist"] = d
+        with obs.span("run.fetch"):
             values = np.stack([post(p.from_blocks(x[q]))
                                for q in range(len(sources))])
-            extra = {"algo": spec.algo, "sources": sources, "dist": dist}
-            return Result(values, stats, p, extra, policy=pol,
-                          graph=self.g)
-        x0 = jnp.stack([p.to_blocks(x0f(s), pad) for s in sources])
-        kw = dict(apply_kind=apply_kind, damping=pol.damping, tol=pol.tol,
-                  max_sweeps=pol.max_sweeps, kernel=kern)
-        if pol.mode == "async":
-            ch0 = jnp.stack([self._frontier(p, s) for s in sources])
-            x, stats = eng.run_async_batched(p, x0, changed0=ch0, **kw)
-        else:
-            ch0 = (jnp.stack([self._frontier(p, s) for s in sources])
-                   if kern.fuse_frontier else None)
-            x, stats = eng.run_sync_batched(p, x0, changed0=ch0, **kw)
-        values = np.stack([post(p.from_blocks(x[q]))
-                           for q in range(len(sources))])
-        extra = {"algo": spec.algo, "sources": sources}
         return Result(values, stats, p, extra, policy=pol, graph=self.g)
 
-    def _run_batched_dist_fallback(self, spec, pol, p, x0f, pad,
-                                   apply_kind, post, sources) -> Result:
+    def _run_batched_dist_fallback(self, spec, pol, sources) -> Result:
         """``query_axis=0`` escape hatch: the retired per-source loop
         through the sequential distributed engine.  Kept for debugging
         mesh factorizations against a known-serial reference — the
         default batched path is one 2-D shard_map dispatch."""
+        p, _, x0f, pad, apply_kind, post = self._relaxation_setup(spec, pol)
         xs, sweeps, conv = [], [], []
         for s in sources:
             x0q = p.to_blocks(x0f(s), pad)

@@ -39,7 +39,7 @@ from .graph import Graph, to_bsr
 from ..kernels import ops
 from ..kernels.bsr_spmv import lane_tiles
 from ..kernels.spec import KernelSpec, as_kernel_spec
-from .. import resilience
+from .. import obs, resilience
 
 
 @dataclasses.dataclass
@@ -261,6 +261,11 @@ def prepare(g: Graph, semiring_name: str, b: int = 32,
     pull=True computes over in-edges (y_i = ⊕_j A[j→i] ⊗ x_j), the natural
     direction for relaxation/propagation algorithms.
     normalize="out_stochastic": edge j→i gets weight 1/outdeg(j) (PageRank).
+
+    The phases are timed as spans (``repro.obs``): ``plan.cluster``,
+    ``plan.tile`` (permute, transpose, BSR build, lane-tile padding,
+    group and halo geometry) and ``plan.upload``, which ends when the
+    plan's arrays are on the device.
     """
     ring = sr.get(semiring_name)
     n = g.n
@@ -270,59 +275,68 @@ def prepare(g: Graph, semiring_name: str, b: int = 32,
         g = Graph(n=n, indptr=g.indptr, indices=g.indices,
                   weights=w.astype(np.float32))
     num_clusters = num_clusters or max(1, min(64, n // max(b, 1)))
-    c = (cluster_graph(g, num_clusters, seed=seed) if clustered
-         else identity_clustering(g, num_clusters))
-    g2 = g.permute(c.perm.astype(np.int32))
-    gm = g2.transpose() if pull else g2
-    bsr = to_bsr(gm, b, pad_value=float(ring.zero))
+    with obs.span("plan.cluster"):
+        c = (cluster_graph(g, num_clusters, seed=seed) if clustered
+             else identity_clustering(g, num_clusters))
+    with obs.span("plan.tile"):
+        g2 = g.permute(c.perm.astype(np.int32))
+        gm = g2.transpose() if pull else g2
+        bsr = to_bsr(gm, b, pad_value=float(ring.zero))
 
-    # group (engine-level cluster) geometry: contiguous row-block ranges
-    s = min(c.num_clusters, bsr.r)
-    gb = (bsr.r + s - 1) // s
-    r_pad = s * gb
-    # tile slots padded so a row-block's sources fill whole 128-lane
-    # columns: the Pallas kernels then take the device image as it is
-    m = lane_tiles(b)
-    k = -(-bsr.k_max // m) * m
-    vals = np.full((r_pad, b, k * b), float(ring.zero), dtype=np.float32)
-    cols = np.zeros((r_pad, k), dtype=np.int32)
-    nnz = np.zeros(r_pad, dtype=np.int32)
-    vals[: bsr.r, :, : bsr.k_max * b] = bsr.block_vals
-    cols[: bsr.r, : bsr.k_max] = bsr.block_cols
-    nnz[: bsr.r] = bsr.block_nnz
+        # group (engine-level cluster) geometry: contiguous row-block ranges
+        s = min(c.num_clusters, bsr.r)
+        gb = (bsr.r + s - 1) // s
+        r_pad = s * gb
+        # tile slots padded so a row-block's sources fill whole 128-lane
+        # columns: the Pallas kernels then take the device image as it is
+        m = lane_tiles(b)
+        k = -(-bsr.k_max // m) * m
+        vals = np.full((r_pad, b, k * b), float(ring.zero),
+                       dtype=np.float32)
+        cols = np.zeros((r_pad, k), dtype=np.int32)
+        nnz = np.zeros(r_pad, dtype=np.int32)
+        vals[: bsr.r, :, : bsr.k_max * b] = bsr.block_vals
+        cols[: bsr.r, : bsr.k_max] = bsr.block_cols
+        nnz[: bsr.r] = bsr.block_nnz
 
-    valid = np.zeros((r_pad, b), dtype=bool)
-    valid.reshape(-1)[: n] = True  # permuted ids are 0..n-1
-    outdeg0 = np.zeros(r_pad * b, dtype=np.int64)
-    outdeg0[: n] = np.diff(g2.indptr)
-    dangling = valid & (outdeg0.reshape(r_pad, b) == 0)
+        valid = np.zeros((r_pad, b), dtype=bool)
+        valid.reshape(-1)[: n] = True  # permuted ids are 0..n-1
+        outdeg0 = np.zeros(r_pad * b, dtype=np.int64)
+        outdeg0[: n] = np.diff(g2.indptr)
+        dangling = valid & (outdeg0.reshape(r_pad, b) == 0)
 
-    grp = np.arange(r_pad) // gb
-    group_tiles = np.zeros(s, dtype=np.float64)
-    np.add.at(group_tiles, grp, nnz)
-    group_edges = np.zeros(s, dtype=np.float64)
-    edge_nnz = np.zeros(r_pad, dtype=np.float64)
-    edge_nnz[: bsr.r] = bsr.edge_nnz
-    np.add.at(group_edges, grp, edge_nnz)
-    # halo: tiles whose source col-block lives outside the group row range
-    ext = ((cols // gb) != grp[:, None]) & \
-          (np.arange(k)[None, :] < nnz[:, None])
-    group_ext_tiles = np.zeros(s, dtype=np.float64)
-    np.add.at(group_ext_tiles, grp, ext.sum(axis=1))
-    row_ext = ext.sum(axis=1).astype(np.float64)
+        grp = np.arange(r_pad) // gb
+        group_tiles = np.zeros(s, dtype=np.float64)
+        np.add.at(group_tiles, grp, nnz)
+        group_edges = np.zeros(s, dtype=np.float64)
+        edge_nnz = np.zeros(r_pad, dtype=np.float64)
+        edge_nnz[: bsr.r] = bsr.edge_nnz
+        np.add.at(group_edges, grp, edge_nnz)
+        # halo: tiles whose source col-block lives outside the group row range
+        ext = ((cols // gb) != grp[:, None]) & \
+              (np.arange(k)[None, :] < nnz[:, None])
+        group_ext_tiles = np.zeros(s, dtype=np.float64)
+        np.add.at(group_ext_tiles, grp, ext.sum(axis=1))
+        row_ext = ext.sum(axis=1).astype(np.float64)
+        perm = np.asarray(c.perm)
+        inv_perm = np.argsort(perm)
 
+    with obs.span("plan.upload"):
+        dev = dict(
+            vals=jnp.asarray(vals), cols=jnp.asarray(cols),
+            nnz=jnp.asarray(nnz), valid=jnp.asarray(valid),
+            dangling=jnp.asarray(dangling),
+            group_tiles=jnp.asarray(group_tiles, jnp.float32),
+            group_edges=jnp.asarray(group_edges, jnp.float32),
+            group_ext_tiles=jnp.asarray(group_ext_tiles, jnp.float32),
+            row_edges=jnp.asarray(edge_nnz, jnp.float32),
+            row_ext=jnp.asarray(row_ext, jnp.float32))
+        jax.block_until_ready(dev)
     return Prepared(
-        vals=jnp.asarray(vals), cols=jnp.asarray(cols), nnz=jnp.asarray(nnz),
-        valid=jnp.asarray(valid), dangling=jnp.asarray(dangling),
-        group_tiles=jnp.asarray(group_tiles, jnp.float32),
-        group_edges=jnp.asarray(group_edges, jnp.float32),
-        group_ext_tiles=jnp.asarray(group_ext_tiles, jnp.float32),
-        row_edges=jnp.asarray(edge_nnz, jnp.float32),
-        row_ext=jnp.asarray(row_ext, jnp.float32),
-        n=n, b=b, r_pad=r_pad, k_max=k, gb=gb, s=s,
-        semiring=semiring_name, perm=np.asarray(c.perm),
-        inv_perm=np.argsort(np.asarray(c.perm)), clustering=c,
-        tiles_total=float(nnz.sum()), edges_total=float(edge_nnz.sum()))
+        **dev, n=n, b=b, r_pad=r_pad, k_max=k, gb=gb, s=s,
+        semiring=semiring_name, perm=perm, inv_perm=inv_perm,
+        clustering=c, tiles_total=float(nnz.sum()),
+        edges_total=float(edge_nnz.sum()))
 
 
 # ---------------------------------------------------------------------------
@@ -440,6 +454,7 @@ def _resolve_kernel(kernel, impl: str) -> KernelSpec:
 
 @functools.partial(jax.jit, static_argnames=(
     "semiring_name", "apply_kind", "max_sweeps", "kernel"))
+@jax.named_scope("engine.sync_loop")
 def _sync_loop(vals, cols, nnz, valid, dangling, x0, damping, tol, inv_n,
                semiring_name, apply_kind, max_sweeps, kernel):
     ring = sr.get(semiring_name)
@@ -451,9 +466,11 @@ def _sync_loop(vals, cols, nnz, valid, dangling, x0, damping, tol, inv_n,
 
     def body(st):
         i, x, _ = st
-        y = spmv(vals, cols, nnz, x, semiring=semiring_name)
-        x_new, imp = _apply(apply_kind, ring, y, x, valid, damping, inv_n,
-                            tol)
+        with jax.named_scope("sweep.spmv"):
+            y = spmv(vals, cols, nnz, x, semiring=semiring_name)
+        with jax.named_scope("sweep.apply"):
+            x_new, imp = _apply(apply_kind, ring, y, x, valid, damping,
+                                inv_n, tol)
         return i + 1, x_new, ~jnp.any(imp)
 
     i, x, done = jax.lax.while_loop(cond, body, (jnp.int32(0), x0, False))
@@ -462,6 +479,7 @@ def _sync_loop(vals, cols, nnz, valid, dangling, x0, damping, tol, inv_n,
 
 @functools.partial(jax.jit, static_argnames=(
     "semiring_name", "apply_kind", "max_sweeps", "gb", "s", "kernel"))
+@jax.named_scope("engine.sync_loop")
 def _sync_loop_fused(vals, cols, nnz, valid, row_edges, row_ext, x0,
                      changed0, damping, tol, inv_n, semiring_name,
                      apply_kind, max_sweeps, gb, s, kernel):
@@ -490,12 +508,15 @@ def _sync_loop_fused(vals, cols, nnz, valid, row_edges, row_ext, x0,
 
     def body(st):
         i, x, ch, _, c = st
-        act = jnp.any(ch[cols] & live, axis=1)
-        if bias:
-            act = act | ((i == 0) & valid_rows)
-        x, ch, imp_any = spmv(vals, cols, nnz, x, x, valid, act, damping,
-                              tol, inv_n, semiring=semiring_name,
-                              apply_kind=apply_kind)
+        with jax.named_scope("sweep.frontier"):
+            act = jnp.any(ch[cols] & live, axis=1)
+            if bias:
+                act = act | ((i == 0) & valid_rows)
+        with jax.named_scope("sweep.spmv"):
+            x, ch, imp_any = spmv(vals, cols, nnz, x, x, valid, act,
+                                  damping, tol, inv_n,
+                                  semiring=semiring_name,
+                                  apply_kind=apply_kind)
         af = act.astype(jnp.float32)
         g_tiles = (af * nnz_f).reshape(s, gb).sum(axis=1)
         c = dict(
@@ -560,6 +581,7 @@ def run_sync(p: Prepared, x0: jnp.ndarray, apply_kind: str = "relax",
 
 @functools.partial(jax.jit, static_argnames=(
     "semiring_name", "apply_kind", "max_sweeps", "gb", "s", "kernel"))
+@jax.named_scope("engine.async_loop")
 def _async_loop(vals, cols, nnz, valid, dangling, group_tiles, group_edges,
                 group_ext, row_edges, row_ext, x0, changed0, damping, tol,
                 inv_n, semiring_name, apply_kind, max_sweeps, gb, s,
@@ -584,33 +606,39 @@ def _async_loop(vals, cols, nnz, valid, dangling, group_tiles, group_edges,
         # data readiness: any live input tile whose source block changed —
         # either last sweep (ch_prev) or earlier THIS sweep (ch_next, the
         # Gauss-Seidel freshness path).
-        ch = ch_prev | ch_next
-        live = lane < nnz_g[:, None]
-        active = jnp.any(ch[cols_g] & live)
-        if first_touch:
-            active = active | ~ran[sidx]
-        if fused:
-            # row-granular frontier inside the group: the kernel's active
-            # list skips the group's untouched row-blocks entirely.
-            vg = jax.lax.dynamic_slice_in_dim(valid, row0, gb, 0)
-            act_rows = jnp.any(ch[cols_g] & live, axis=1)
+        with jax.named_scope("sweep.frontier"):
+            ch = ch_prev | ch_next
+            live = lane < nnz_g[:, None]
+            active = jnp.any(ch[cols_g] & live)
             if first_touch:
-                act_rows = act_rows | (~ran[sidx] & jnp.any(vg, axis=1))
+                active = active | ~ran[sidx]
+            if fused:
+                # row-granular frontier inside the group: the kernel's active
+                # list skips the group's untouched row-blocks entirely.
+                vg = jax.lax.dynamic_slice_in_dim(valid, row0, gb, 0)
+                act_rows = jnp.any(ch[cols_g] & live, axis=1)
+                if first_touch:
+                    act_rows = act_rows | (~ran[sidx]
+                                           & jnp.any(vg, axis=1))
 
         def do(args):
             x, ch_next = args
             xg = jax.lax.dynamic_slice_in_dim(x, row0, gb, 0)
             vg = jax.lax.dynamic_slice_in_dim(valid, row0, gb, 0)
             if fused:
-                x_new, imp_rows, _ = spmv(
-                    vals_g, cols_g, nnz_g, x, xg, vg, act_rows, damping,
-                    tol, inv_n, semiring=semiring_name,
-                    apply_kind=apply_kind)
+                with jax.named_scope("sweep.spmv"):
+                    x_new, imp_rows, _ = spmv(
+                        vals_g, cols_g, nnz_g, x, xg, vg, act_rows,
+                        damping, tol, inv_n, semiring=semiring_name,
+                        apply_kind=apply_kind)
             else:
-                y = spmv(vals_g, cols_g, nnz_g, x, semiring=semiring_name)
-                x_new, imp = _apply(apply_kind, ring, y, xg, vg, damping,
-                                    inv_n, tol)
-                imp_rows = jnp.any(imp, axis=1)
+                with jax.named_scope("sweep.spmv"):
+                    y = spmv(vals_g, cols_g, nnz_g, x,
+                             semiring=semiring_name)
+                with jax.named_scope("sweep.apply"):
+                    x_new, imp = _apply(apply_kind, ring, y, xg, vg,
+                                        damping, inv_n, tol)
+                    imp_rows = jnp.any(imp, axis=1)
             x = jax.lax.dynamic_update_slice_in_dim(x, x_new, row0, 0)
             ch_next = jax.lax.dynamic_update_slice_in_dim(
                 ch_next, imp_rows, row0, 0)

@@ -40,6 +40,13 @@ Failure handling (the self-healing half):
   * ``stop(drain=False)`` resolves everything still pending with a
     structured ``ServerClosed`` (a ``Backpressure`` subclass) instead of
     leaving futures hanging forever.
+
+Spans (``repro.obs``): ``request.queue`` (submit → the wave close that
+took the request; attributes ``ticket``, ``wave``), ``wave.launch``
+(wave close → its dispatch starts on the worker thread), ``wave`` (the
+dispatch; ``wave``, ``size``, ``closed``) and ``wave.resolve`` (from the
+end of the wave's run: slicing each ticket's ``Result`` and setting the
+futures).
 """
 
 from __future__ import annotations
@@ -50,9 +57,9 @@ import random
 import threading
 import time
 from concurrent.futures import Future
-from typing import Dict, List, Optional, Tuple
+from typing import Dict, List, NamedTuple, Optional, Tuple
 
-from .. import resilience
+from .. import obs, resilience
 from ..core.api import QuerySpec
 from .graph import GraphService, _Pending
 
@@ -172,6 +179,20 @@ class _Request:
     t_deadline: Optional[float]     # monotonic, None = no deadline
     attempt: int = 0                # retries consumed so far
     settled: bool = False           # resolution claimed (guarded by _cv)
+    # the span clock (time.perf_counter_ns) at the first submit
+    t_submit_ns: int = dataclasses.field(default_factory=time.perf_counter_ns)
+
+
+class _Closed(NamedTuple):
+    """One wave ``_close_waves`` popped, and why: ``full`` (the group
+    reached ``max_wave``, or non-coalescible requests, which no other
+    request can join), ``wait`` (its oldest request waited
+    ``max_wait_s``) or ``forced`` (``stop`` flushed it)."""
+
+    key: Optional[tuple]
+    wave: List[_Request]
+    why: str
+    t_ns: int                       # span clock at the close
 
 
 @dataclasses.dataclass
@@ -182,6 +203,8 @@ class _Inflight:
     key: Optional[tuple]
     wave: List[_Request]
     deadline: Optional[float]       # monotonic watchdog reap time
+    closed: str                     # _Closed.why
+    t_closed_ns: int                # span clock at the close
     wid: int = -1
     abandoned: bool = False         # watchdog gave up on the dispatcher
     slot_acquired: bool = False
@@ -199,6 +222,13 @@ class WaveScheduler:
     Not started until ``start()`` — a paused scheduler just accumulates
     requests, which is also what makes batching deterministic for tests
     and benchmarks.
+
+    ``stats()`` counts, besides the requests' outcomes, ``waves`` and
+    ``wave_queries`` (waves dispatched and the requests they held), and
+    why each wave closed: ``closed_full`` (a group reached ``max_wave``;
+    a batch of non-coalescible requests counts here too, as nothing can
+    join it), ``closed_wait`` (its oldest request waited ``max_wait_s``)
+    and ``closed_forced`` (``stop`` flushed it).
     """
 
     def __init__(self, service: GraphService, policy: WavePolicy):
@@ -222,7 +252,8 @@ class WaveScheduler:
         self._stats = dict(waves=0, wave_queries=0, coalesced_waves=0,
                            max_wave=0, expired=0, cancelled=0,
                            completed=0, failed=0, retries=0,
-                           retry_exhausted=0, watchdog_timeouts=0)
+                           retry_exhausted=0, watchdog_timeouts=0,
+                           closed_full=0, closed_wait=0, closed_forced=0)
 
     # -- client side -----------------------------------------------------
 
@@ -318,8 +349,8 @@ class WaveScheduler:
             with self._cv:
                 for req in parked:
                     self._enqueue_locked(req)
-            for key, wave in self._close_waves(force=True):
-                ent = self._register_wave(key, wave)
+            for c in self._close_waves(force=True):
+                ent = self._register_wave(c)
                 self._dispatch(ent)       # synchronous final flush
             self._join_inflight(timeout=None)
         else:
@@ -327,8 +358,8 @@ class WaveScheduler:
             for req in parked:
                 if _claim(req.future):
                     self._fail(req, err)
-            for _, wave in self._close_waves(force=True):
-                for r in wave:
+            for c in self._close_waves(force=True):
+                for r in c.wave:
                     if _claim(r.future):
                         self._fail(r, err)
                 with self._cv:
@@ -395,8 +426,8 @@ class WaveScheduler:
                     self._cv.wait(timeout=wait)
                     if not self._running:
                         return
-            for key, wave in self._close_waves(force=False):
-                ent = self._register_wave(key, wave)
+            for c in self._close_waves(force=False):
+                ent = self._register_wave(c)
                 t = threading.Thread(
                     target=self._dispatch, args=(ent,),
                     name="repro-wave-dispatch", daemon=True)
@@ -431,14 +462,14 @@ class WaveScheduler:
                 upd(ent.deadline)
         return due
 
-    def _close_waves(self, force: bool
-                     ) -> List[Tuple[Optional[tuple], List[_Request]]]:
+    def _close_waves(self, force: bool) -> List[_Closed]:
         """Pop every wave that is ready (full / waited out / forced),
         expiring dead-on-arrival requests first so they never occupy a
-        row.  Returns [(wave_key or None, requests)]."""
+        row."""
         expired: List[_Request] = []
-        todo: List[Tuple[Optional[tuple], List[_Request]]] = []
+        todo: List[_Closed] = []
         now = time.monotonic()
+        now_ns = time.perf_counter_ns()
         with self._cv:
             ncancel = self._purge_cancelled(self._singles)
             for dq in self._groups.values():
@@ -449,20 +480,28 @@ class WaveScheduler:
                 self._singles.clear()
                 self._pending -= len(wave)
                 self._inflight += 1
-                todo.append((None, wave))
+                todo.append(_Closed(None, wave, "full", now_ns))
             for key in list(self._groups):
                 dq = self._groups[key]
                 self._expire(dq, now, expired)
-                while dq and (force or len(dq) >= self.policy.max_wave
-                              or now - dq[0].t_submit
-                              >= self.policy.max_wait_s):
+                while dq:
+                    if len(dq) >= self.policy.max_wave:
+                        why = "full"
+                    elif now - dq[0].t_submit >= self.policy.max_wait_s:
+                        why = "wait"
+                    elif force:
+                        why = "forced"
+                    else:
+                        break
                     wave = [dq.popleft() for _ in
                             range(min(len(dq), self.policy.max_wave))]
                     self._pending -= len(wave)
                     self._inflight += 1
-                    todo.append((key, wave))
+                    todo.append(_Closed(key, wave, why, now_ns))
                 if not dq:
                     del self._groups[key]
+            for c in todo:
+                self._stats["closed_" + c.why] += 1
             self._stats["expired"] += len(expired)
             for r in expired:
                 r.settled = True
@@ -504,16 +543,20 @@ class WaveScheduler:
 
     # -- dispatch (per-wave worker threads) ------------------------------
 
-    def _register_wave(self, key: Optional[tuple],
-                       wave: List[_Request]) -> _Inflight:
+    def _register_wave(self, c: _Closed) -> _Inflight:
         """Record one closed wave as in-flight (``_close_waves`` already
-        counted it) so the watchdog can see it."""
-        ent = _Inflight(key, wave, self._wave_deadline(key, wave))
+        counted it) so the watchdog can see it, and each request's wait
+        in the queue as a ``request.queue`` span."""
+        ent = _Inflight(c.key, c.wave, self._wave_deadline(c.key, c.wave),
+                        c.why, c.t_ns)
         with self._cv:
             wid = self._next_wave_id
             self._next_wave_id += 1
             self._entries[wid] = ent
             ent.wid = wid
+        for r in c.wave:
+            obs.record("request.queue", r.t_submit_ns, c.t_ns,
+                       ticket=r.ticket, wave=wid)
         return ent
 
     def _wave_deadline(self, key: Optional[tuple],
@@ -551,10 +594,20 @@ class WaveScheduler:
                 self._cv.notify_all()
 
     def _execute_wave(self, ent: _Inflight) -> None:
-        key, wave = ent.key, ent.wave
-        live = [r for r in wave if _claim(r.future)]
+        obs.record("wave.launch", ent.t_closed_ns, time.perf_counter_ns(),
+                   wave=ent.wid)
+        live = [r for r in ent.wave if _claim(r.future)]
         if not live:
             return
+        with obs.span("wave", wave=ent.wid, size=len(live),
+                      closed=ent.closed) as span:
+            self._run_live(ent, live, span)
+
+    def _run_live(self, ent: _Inflight, live: List[_Request],
+                  span) -> None:
+        """Run the claimed requests of a wave inside its ``wave`` span
+        (``span``), and settle each."""
+        key = ent.key
         try:
             resilience.fire("sched.dispatch",
                             name=key[0] if key else None,
@@ -573,11 +626,10 @@ class WaveScheduler:
                 try:
                     res = self.service.run(r.name, r.spec)
                 except Exception as e:
-                    if self._take(ent, r):
-                        self._resolve_failure(r, e)
-                else:
-                    if self._take(ent, r):
-                        self._ok(r, res)
+                    res = e
+                with obs.span("wave.resolve",
+                              start_ns=span.last_child_end_ns):
+                    self._settle(ent, r, res)
                 self._note_wave(1)
             return
         name, algo, pol = key
@@ -586,15 +638,19 @@ class WaveScheduler:
             out = self.service._run_wave(name, algo, pol, pend)
         except Exception as e:   # defensive: _run_wave maps per-ticket
             out = {r.ticket: e for r in live}
-        for r in live:
-            res = out[r.ticket]
-            if not self._take(ent, r):
-                continue
-            if isinstance(res, Exception):
-                self._resolve_failure(r, res)
-            else:
-                self._ok(r, res)
+        # _run_wave's last step, slicing each ticket's Result, ends it
+        with obs.span("wave.resolve", start_ns=span.last_child_end_ns):
+            for r in live:
+                self._settle(ent, r, out[r.ticket])
         self._note_wave(len(live))
+
+    def _settle(self, ent: _Inflight, req: _Request, res) -> None:
+        if not self._take(ent, req):
+            return
+        if isinstance(res, Exception):
+            self._resolve_failure(req, res)
+        else:
+            self._ok(req, res)
 
     def _take(self, ent: _Inflight, req: _Request) -> bool:
         """Dispatcher-side claim of one request's resolution; loses to

@@ -243,6 +243,11 @@ class GraphServer:
     # -- introspection ---------------------------------------------------
 
     def stats(self) -> dict:
+        """Counters of the three layers: ``server`` (admission refusals,
+        plan warming), ``scheduler`` (``WaveScheduler.stats``: request
+        outcomes, ``waves`` and ``wave_queries``, and why each wave
+        closed, ``closed_full`` / ``closed_wait`` / ``closed_forced``)
+        and ``service`` (coalescing and the plan store)."""
         with self._lock:
             s = dict(rejected_pending=self._rejected_pending,
                      rejected_thrash=self._rejected_thrash,
