@@ -523,7 +523,10 @@ class GraphProcessor:
         Timed as three spans (``repro.obs``): ``run.prep`` (plan lookup,
         initial state built and uploaded), ``run.device`` (dispatch until
         the sweep count is on the host) and ``run.fetch`` (device → host
-        copies, un-permute, ``post``)."""
+        copies, un-permute, ``post``).  ``run.device`` says how the
+        engine gathered source blocks (``gather``: ``"wave"``, once for
+        the whole batch, or ``"per_query"``) and for how many queries
+        (``q``)."""
         if spec.batched:
             return self._run_batched(spec, pol)
         with obs.span("run.prep"):
@@ -531,7 +534,7 @@ class GraphProcessor:
                 spec, pol)
             src = spec.sources[0] if spec.sources else None
             x0 = p.to_blocks(x0f(src), pad)
-        with obs.span("run.device"):
+        with obs.span("run.device", gather="per_query", q=1):
             x, stats, extra = self._dispatch(pol, p, x0, apply_kind, src,
                                              kern)
         with obs.span("run.fetch"):
@@ -625,7 +628,9 @@ class GraphProcessor:
         extra = {"algo": spec.algo, "sources": sources}
         kw = dict(apply_kind=apply_kind, damping=pol.damping, tol=pol.tol,
                   max_sweeps=pol.max_sweeps)
-        with obs.span("run.device"):
+        wave = not dist and pol.mode == "async" and eng.wave_path(kern)
+        with obs.span("run.device", gather="wave" if wave else "per_query",
+                      q=len(sources)):
             if not dist:
                 run = (eng.run_async_batched if pol.mode == "async"
                        else eng.run_sync_batched)

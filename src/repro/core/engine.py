@@ -721,7 +721,10 @@ def run_async(p: Prepared, x0: jnp.ndarray, apply_kind: str = "relax",
 # traced program.  JAX's while_loop batching rule masks updates per query,
 # so each query stops relaxing once it converges; reported sweeps is the
 # straggler's (the batch retires together, like a wavefront of independent
-# frontiers through the same NALE array).
+# frontiers through the same NALE array).  The async runner on the unfused
+# ref kernel carries the query axis itself instead (``_async_wave_loop``),
+# with the same per-query trajectory: under vmap the state of Q queries
+# lies query-major, and each tile's gather would read Q strided blocks.
 
 
 def run_sync_batched(p: Prepared, x0: jnp.ndarray,
@@ -762,6 +765,114 @@ def run_sync_batched(p: Prepared, x0: jnp.ndarray,
                         "sync", work_sweeps=int(sweeps.sum()))
 
 
+@functools.partial(jax.jit, static_argnames=(
+    "semiring_name", "apply_kind", "max_sweeps", "gb", "s", "kernel"))
+@jax.named_scope("engine.async_wave_loop")
+def _async_wave_loop(vals, cols, nnz, valid, group_tiles, group_edges,
+                     group_ext, x0, changed0, damping, tol, inv_n,
+                     semiring_name, apply_kind, max_sweeps, gb, s, kernel):
+    """``_async_loop`` for a wave of Q queries on the unfused kernel, with
+    the query axis carried inside each row-block: x as (r_pad, Q*B) and
+    the change flags as (r_pad, Q).  A tile's source block is then one
+    contiguous row of Q*B values for the whole wave's gather, and its
+    flags one row of Q.
+
+    Each query follows the trajectory ``jax.vmap(_async_loop)`` gives it:
+    every group is computed for the wave, and a query keeps the update
+    only where its own frontier (or first touch) made the group active;
+    a converged query's state and counters stay frozen while the
+    straggler sweeps.  x0: (Q, r_pad, B), changed0: (Q, r_pad); returns
+    per-query sweeps, x as (Q, r_pad, B), done and counters (each (Q,)).
+    """
+    ring = sr.get(semiring_name)
+    spmv = ops.select_kernel("bsr_spmv_wave", kernel)
+    nq, r_pad, b = x0.shape
+    lane = jnp.arange(cols.shape[1])[None, :, None]
+    first_touch = sr.rule(apply_kind).bias
+
+    def sweep_step(carry, sidx):
+        # ch: the flags the frontier test reads, last sweep's | this
+        # sweep's so far; ch_next: this sweep's alone.  A group's rows of
+        # ch_next are written once a sweep, by its own step.
+        x, ch, ch_next, ran, counters, run = carry
+        row0 = sidx * gb
+        # the group's rows of x and ch are read by row gathers, not
+        # slices: a slice would make the compiler lay out all of x or ch
+        # as the update wants it, once per group
+        rows = row0 + jnp.arange(gb)
+        vals_g = jax.lax.dynamic_slice_in_dim(vals, row0, gb, 0)
+        cols_g = jax.lax.dynamic_slice_in_dim(cols, row0, gb, 0)
+        nnz_g = jax.lax.dynamic_slice_in_dim(nnz, row0, gb, 0)
+        with jax.named_scope("sweep.frontier"):
+            active = jnp.any(ch[cols_g] & (lane < nnz_g[:, None, None]),
+                             axis=(0, 1))
+            if first_touch:
+                active = active | ~ran[sidx]
+            active = active & run
+        xg = x[rows]
+        vg = jnp.tile(jax.lax.dynamic_slice_in_dim(valid, row0, gb, 0),
+                      (1, nq))
+        with jax.named_scope("sweep.spmv"):
+            y = spmv(vals_g, cols_g, nnz_g, x.reshape(r_pad, nq, b),
+                     semiring=semiring_name).reshape(gb, nq * b)
+        with jax.named_scope("sweep.apply"):
+            x_new, imp = _apply(apply_kind, ring, y, xg, vg, damping,
+                                inv_n, tol)
+            x_new = jnp.where(jnp.repeat(active, b), x_new, xg)
+            imp_rows = jnp.any(imp.reshape(gb, nq, b), axis=2) & active
+        x = jax.lax.dynamic_update_slice_in_dim(x, x_new, row0, 0)
+        ch = jax.lax.dynamic_update_slice_in_dim(
+            ch, ch[rows] | imp_rows, row0, 0)
+        ch_next = jax.lax.dynamic_update_slice_in_dim(
+            ch_next, imp_rows, row0, 0)
+        ran = ran.at[sidx].set(ran[sidx] | active)
+        af = active.astype(jnp.float32)
+        g_tiles = af * group_tiles[sidx]
+        counters = dict(
+            counters,
+            tile_work=counters["tile_work"] + g_tiles,
+            edge_work=counters["edge_work"] + af * group_edges[sidx],
+            halo=counters["halo"] + af * group_ext[sidx],
+            active=counters["active"] + af,
+            sweep_max=jnp.maximum(counters["sweep_max"], g_tiles))
+        return (x, ch, ch_next, ran, counters, run), None
+
+    def cond(st):
+        i, x, ch, ran, done, _ = st
+        return jnp.any(~done & (i < max_sweeps))
+
+    def body(st):
+        i, x, ch_prev, ran, done, counters = st
+        run = ~done & (i < max_sweeps)
+        c = dict(counters, sweep_max=jnp.zeros(nq, jnp.float32))
+        (x, _, ch_next, ran, c, _), _ = jax.lax.scan(
+            sweep_step, (x, ch_prev, jnp.zeros_like(ch_prev), ran, c, run),
+            jnp.arange(s, dtype=jnp.int32))
+        c = dict(c, crit=c["crit"] + c["sweep_max"])
+        c = {k: jnp.where(run, v, counters[k]) for k, v in c.items()}
+        return (jnp.where(run, i + 1, i), x,
+                jnp.where(run, ch_next, ch_prev), ran,
+                jnp.where(run, ~jnp.any(ch_next, axis=0), done), c)
+
+    zeros = jnp.zeros(nq, jnp.float32)
+    counters0 = dict(tile_work=zeros, edge_work=zeros, halo=zeros,
+                     active=zeros, crit=zeros, sweep_max=zeros)
+    x = jnp.transpose(x0, (1, 0, 2)).reshape(r_pad, nq * b)
+    i, x, ch, ran, done, counters = jax.lax.while_loop(
+        cond, body, (jnp.zeros(nq, jnp.int32), x, changed0.T,
+                     jnp.zeros((s, nq), dtype=bool),
+                     jnp.zeros(nq, dtype=bool), counters0))
+    x = jnp.transpose(x.reshape(r_pad, nq, b), (1, 0, 2))
+    return i, x, done, counters
+
+
+def wave_path(kernel) -> bool:
+    """Whether ``run_async_batched`` runs a wave on ``_async_wave_loop``:
+    the resolved kernel has a wave form (the unfused ref kernel).  The
+    fused and Pallas kernels take one query's x and run under vmap."""
+    return ops.has_kernel("bsr_spmv_wave", kernel)
+
+
 def run_async_batched(p: Prepared, x0: jnp.ndarray,
                       apply_kind: str = "relax", damping: float = 0.85,
                       tol: float = 1e-6, max_sweeps: int = 10_000,
@@ -775,6 +886,15 @@ def run_async_batched(p: Prepared, x0: jnp.ndarray,
     inv_n = jnp.float32(1.0 / max(p.n, 1))
     if changed0 is None:
         changed0 = jnp.ones((x0.shape[0], p.r_pad), dtype=bool)
+    if wave_path(spec):
+        i, x, done, c = _async_wave_loop(
+            p.vals, p.cols, p.nnz, p.valid, p.group_tiles, p.group_edges,
+            p.group_ext_tiles, x0, changed0, jnp.float32(damping),
+            jnp.float32(tol), inv_n, p.semiring, apply_kind, max_sweeps,
+            p.gb, p.s, spec)
+        sweeps = np.asarray(i)
+        return x, _counter_stats(p, int(sweeps.max(initial=0)),
+                                 bool(np.all(done)), c, "async")
 
     def one(x0q, ch0q):
         return _async_loop(

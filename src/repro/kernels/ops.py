@@ -19,6 +19,10 @@ Registered call signatures (one contract per (op, fused) pair):
 
   ("bsr_spmv", fused=False)  fn(vals, cols, nnz, x, semiring=...)
                              -> y (R, B)
+  ("bsr_spmv_wave", fused=False)
+                             fn(vals, cols, nnz, x, semiring=...)
+                             -> y (R, Q, B), x (C, Q, B): a wave of Q
+                             vectors, queries inside each row-block
   ("bsr_spmv", fused=True)   fn(vals, cols, nnz, x, xg, valid, act_rows,
                                 damping, tol, inv_n, semiring=...,
                                 apply_kind=...)
@@ -106,6 +110,12 @@ def select_kernel(op: str, spec=None, platform: Optional[str] = None):
     return builder(spec, resolve_platform(platform))
 
 
+def has_kernel(op: str, spec=None) -> bool:
+    """Whether (op, spec) has a registered kernel; fires no fault site."""
+    spec = as_kernel_spec(spec)
+    return (op, spec.impl, spec.fuse_frontier) in _KERNELS
+
+
 # ---------------------------------------------------------------------------
 # bsr_spmv
 # ---------------------------------------------------------------------------
@@ -123,6 +133,22 @@ def _build_bsr_spmv_ref(spec: KernelSpec, platform: str):
     def fn(block_vals, block_cols, block_nnz, x, semiring="plus_times"):
         del block_nnz  # identity padding makes the bound implicit
         return _bsr_spmv_ref_jit(block_vals, block_cols, x, semiring)
+
+    return fn
+
+
+@functools.partial(jax.jit, static_argnames=("semiring",))
+def _bsr_spmv_wave_ref_jit(block_vals, block_cols, x, semiring):
+    return _ref.bsr_spmv_wave_ref(block_vals, block_cols, x, semiring)
+
+
+@register_kernel("bsr_spmv_wave", "ref")
+def _build_bsr_spmv_wave_ref(spec: KernelSpec, platform: str):
+    del spec, platform
+
+    def fn(block_vals, block_cols, block_nnz, x, semiring="plus_times"):
+        del block_nnz
+        return _bsr_spmv_wave_ref_jit(block_vals, block_cols, x, semiring)
 
     return fn
 

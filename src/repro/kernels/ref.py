@@ -56,6 +56,45 @@ def bsr_spmv_ref(block_vals: jnp.ndarray, block_cols: jnp.ndarray,
     return ring.reduce(ring.mul(block_vals, xs), axis=2)
 
 
+def bsr_spmv_wave_ref(block_vals: jnp.ndarray, block_cols: jnp.ndarray,
+                      x: jnp.ndarray,
+                      semiring: str = "plus_times") -> jnp.ndarray:
+    """``bsr_spmv_ref`` for a wave of Q vectors carried row-major.
+
+    Args:
+      block_vals, block_cols: as for ``bsr_spmv_ref``.
+      x: (C, Q, B) — the wave's Q vectors, queries contiguous inside each
+        row-block.
+      semiring: as for ``bsr_spmv_ref``.
+    Returns:
+      y: (R, Q, B); ``y[:, q]`` is ``bsr_spmv_ref(..., x[:, q], ...)``,
+      with the same ⊗ and ⊕ per element.
+
+    The source gather fetches each tile's block once for the whole wave,
+    one contiguous row of Q*B values; Q vectors carried apart would be Q
+    strided rows of B values per tile.
+    """
+    r = block_vals.shape[0]
+    c, q, b = x.shape
+    k = block_cols.shape[1]
+    rows = x.reshape(c, q * b)[block_cols]            # (R, K, Q*B)
+    xs = rows.reshape(r, k, q, b).transpose(0, 2, 1, 3).reshape(
+        r, q, 1, k * b)                               # (R, Q, 1, K*B)
+    if semiring == "plus_times":
+        return jnp.einsum("rij,rqj->rqi", block_vals, xs[:, :, 0],
+                          precision=jax.lax.Precision.HIGHEST)
+    v = block_vals[:, None]                           # (R, 1, B, K*B)
+    if semiring == "min_plus":
+        return jnp.min(v + xs, axis=3)
+    if semiring == "max_min":
+        return jnp.max(jnp.minimum(v, xs), axis=3)
+    if semiring == "min_select":
+        return jnp.min(jnp.where(jnp.isfinite(v), xs, jnp.inf), axis=3)
+    from ..core import semiring as _sr
+    ring = _sr.get(semiring)
+    return ring.reduce(ring.mul(v, xs), axis=3)
+
+
 # ---------------------------------------------------------------------------
 # flash_attention — exact softmax attention oracle
 # ---------------------------------------------------------------------------

@@ -93,6 +93,22 @@ def test_ref_spmv_compiles(plan):
     assert _fits(c)
 
 
+def test_ref_wave_spmv_compiles(topo, plan):
+    """One group step of the batched async engine's wave: 8 queries
+    carried row-major, so the source gather takes one row of 8*B = 128
+    lanes per tile (the vmapped single-query kernel takes 8 of B)."""
+    one = SingleDeviceSharding(topo.devices[0])
+    gb = R // 64
+    vals = jax.ShapeDtypeStruct((gb, B, KT * B), jnp.float32, sharding=one)
+    cols = jax.ShapeDtypeStruct((gb, KT), jnp.int32, sharding=one)
+    x = jax.ShapeDtypeStruct((R, 8, B), jnp.float32, sharding=one)
+    c = jax.jit(lambda v, cl, xw: ref.bsr_spmv_wave_ref(
+        v, cl, xw, "min_plus")).lower(vals, cols, x).compile()
+    gathers = [ln for ln in c.as_text().splitlines() if " gather(" in ln]
+    assert gathers and all("slice_sizes={1,128}" in ln for ln in gathers)
+    assert _fits(c)
+
+
 @pytest.mark.parametrize("mesh_shape", [(4, 1), (2, 2)])
 def test_distributed_sweep_compiles(topo, mesh_shape):
     mesh = Mesh(np.asarray(topo.devices).reshape(mesh_shape),
