@@ -193,6 +193,10 @@ class PlanKey:
     # by replace(base_key, kernel=requesting_spec), so tunings ride the
     # same (fingerprint, PlanKey) scheme without duplicating plans.
     kernel: Optional[KernelSpec] = None
+    # devices the plan is placed over: 1, the image on one device every
+    # engine takes; n > 1, rows sharded over a mesh of n devices for the
+    # distributed engines (placement.plan_mesh, chosen at build)
+    devices: int = 1
 
 
 @dataclasses.dataclass(frozen=True)
@@ -312,7 +316,9 @@ def degrade_policy(pol: ExecutionPolicy) -> Optional[ExecutionPolicy]:
          a degraded result is the healthy result.
       2. ``mode="distributed"``  →  single-device ``mode="sync"``.  The
          distributed engines are bit-identical to sync at convergence,
-         so again only the cost changes.
+         so again only the cost changes.  ``GraphProcessor.run`` does not
+         take this rung for a plan placed over several devices: the sync
+         engine would need a second, whole plan on one device.
 
     The ladder only changes *how* a query runs, never what it computes —
     which is what lets ``GraphProcessor.run`` retry down it behind the
@@ -346,7 +352,7 @@ class GraphProcessor:
     def __init__(self, g: Graph, b: int = 32,
                  num_clusters: Optional[int] = None, clustered: bool = True,
                  seed: int = 0, policy: Optional[ExecutionPolicy] = None,
-                 store=None):
+                 store=None, max_wave: int = eng.MAX_WAVE):
         self.g = g
         self.b = b
         self.num_clusters = num_clusters
@@ -354,6 +360,7 @@ class GraphProcessor:
         self.seed = seed
         self.policy = policy or ExecutionPolicy()
         self.store = store
+        self.max_wave = int(max_wave)   # widest batch a plan is sized for
         self._plans: Dict[PlanKey, Prepared] = {}
         self._tunings: Dict[PlanKey, dict] = {}  # session-local fallback
         self._variants: Dict[str, Graph] = {"base": g}
@@ -381,45 +388,62 @@ class GraphProcessor:
         return self._variants[name]
 
     def plan_key(self, semiring: str, variant: str = "base",
-                 pull: bool = True, normalize: Optional[str] = None
-                 ) -> PlanKey:
+                 pull: bool = True, normalize: Optional[str] = None,
+                 devices: int = 1) -> PlanKey:
         return PlanKey(semiring, variant, pull, normalize, self.b,
-                       self.num_clusters, self.clustered, self.seed)
+                       self.num_clusters, self.clustered, self.seed,
+                       devices=devices)
+
+    def plan_devices(self, pol: Optional[ExecutionPolicy] = None) -> int:
+        """Devices a plan that serves ``pol`` (default: the session's
+        policy) is placed over: every device for the distributed
+        engines, else one."""
+        pol = pol or self.policy
+        return len(jax.devices()) if pol.mode == "distributed" else 1
 
     def prepare(self, semiring: str, variant: str = "base",
                 pull: bool = True, normalize: Optional[str] = None,
-                kernel: Optional[KernelSpec] = None) -> Prepared:
+                kernel: Optional[KernelSpec] = None,
+                devices: Optional[int] = None) -> Prepared:
         """Fetch (or build and cache) the Prepared image for a plan.
 
         With an injected store the lookup (and LRU/byte accounting) is
         delegated; without one, plans live in a session-local dict.
         Passing a ``kernel`` with ``autotune=True`` also runs (or
         fetches) the measured tuning sweep now, so the first query pays
-        no calibration latency.
+        no calibration latency.  ``devices`` (default:
+        ``plan_devices()`` of the session's policy) is part of the
+        plan's key: a plan placed on one device and one sharded over a
+        mesh are different plans.
         """
-        key = self.plan_key(semiring, variant, pull, normalize)
+        if devices is None:
+            devices = self.plan_devices()
+        key = self.plan_key(semiring, variant, pull, normalize, devices)
         if self.store is not None:
             p = self.store.get(self.g.fingerprint(), key)
             if p is None:
                 self._prepare_calls += 1
-                p = self._build(semiring, variant, pull, normalize)
+                p = self._build(semiring, variant, pull, normalize,
+                                devices)
                 self.store.put(self.g.fingerprint(), key, p)
         else:
             p = self._plans.get(key)
             if p is None:
                 self._prepare_calls += 1
-                p = self._build(semiring, variant, pull, normalize)
+                p = self._build(semiring, variant, pull, normalize,
+                                devices)
                 self._plans[key] = p
         if kernel is not None and kernel.autotune:
             self._ensure_tuning(p, key, kernel)
         return p
 
     def _build(self, semiring: str, variant: str, pull: bool,
-               normalize: Optional[str]) -> Prepared:
+               normalize: Optional[str], devices: int = 1) -> Prepared:
         return eng.prepare(self._variant(variant), semiring, b=self.b,
                            num_clusters=self.num_clusters, pull=pull,
                            clustered=self.clustered, normalize=normalize,
-                           seed=self.seed)
+                           seed=self.seed, devices=devices,
+                           max_wave=self.max_wave)
 
     def cache_info(self) -> dict:
         info = {"plans": len(self._plans),
@@ -487,7 +511,10 @@ class GraphProcessor:
         Run-time engine failures walk the graceful-degradation ladder
         (see :func:`degrade_policy`) while ``policy.degrade`` is set:
         the query re-executes one rung down, each step recorded in
-        ``Result.extra["degraded"]`` as ``{"from", "to", "error"}``.
+        ``Result.extra["degraded"]`` as ``{"from", "to", "error"}``.  A
+        rung whose plan would be placed on other devices is not taken
+        (a distributed plan sharded over a mesh has no single-device
+        copy to fall back on): the failure propagates.
         Errors that mean the request itself is wrong (ValueError /
         TypeError / KeyError — bad spec, ineligible flavor, missing
         kernel registration) always propagate: degradation absorbs
@@ -506,7 +533,8 @@ class GraphProcessor:
                 raise
             except Exception as e:
                 nxt = degrade_policy(pol) if pol.degrade else None
-                if nxt is None:
+                if nxt is None or \
+                        self.plan_devices(nxt) != self.plan_devices(pol):
                     raise
                 steps.append({"from": _policy_desc(pol),
                               "to": _policy_desc(nxt),
@@ -526,7 +554,9 @@ class GraphProcessor:
         copies, un-permute, ``post``).  ``run.device`` says how the
         engine gathered source blocks (``gather``: ``"wave"``, once for
         the whole batch, or ``"per_query"``) and for how many queries
-        (``q``)."""
+        (``q``); a distributed run adds its ``mesh`` (``"4x1"``),
+        ``halo_exchanges`` and ``halo_bytes``, the all_gather payload a
+        device received over the run (``DistStats.halo_bytes``)."""
         if spec.batched:
             return self._run_batched(spec, pol)
         with obs.span("run.prep"):
@@ -534,9 +564,11 @@ class GraphProcessor:
                 spec, pol)
             src = spec.sources[0] if spec.sources else None
             x0 = p.to_blocks(x0f(src), pad)
-        with obs.span("run.device", gather="per_query", q=1):
+        with obs.span("run.device", gather="per_query", q=1) as sp:
             x, stats, extra = self._dispatch(pol, p, x0, apply_kind, src,
                                              kern)
+            if "dist" in extra:
+                sp.attrs.update(extra["dist"].span_attrs())
         with obs.span("run.fetch"):
             values = post(p.from_blocks(x))
         extra = dict(extra, algo=spec.algo,
@@ -550,10 +582,11 @@ class GraphProcessor:
         apply_kind, post) — all read off the algorithm's registered
         ``AlgorithmSpec``; no per-algorithm branching here."""
         a = get_algorithm(spec.algo)
+        devices = self.plan_devices(pol)
         key = self.plan_key(a.semiring, variant=a.variant, pull=a.pull,
-                            normalize=a.normalize)
+                            normalize=a.normalize, devices=devices)
         p = self.prepare(a.semiring, variant=a.variant, pull=a.pull,
-                         normalize=a.normalize)
+                         normalize=a.normalize, devices=devices)
         kern = self._kernel_for_run(p, key, pol.kernel)
         pad = float(a.ring.zero) if a.pad is None else a.pad
         post = a.post if a.post is not None else (lambda v: v)
@@ -630,7 +663,7 @@ class GraphProcessor:
                   max_sweeps=pol.max_sweeps)
         wave = not dist and pol.mode == "async" and eng.wave_path(kern)
         with obs.span("run.device", gather="wave" if wave else "per_query",
-                      q=len(sources)):
+                      q=len(sources)) as sp:
             if not dist:
                 run = (eng.run_async_batched if pol.mode == "async"
                        else eng.run_sync_batched)
@@ -654,6 +687,8 @@ class GraphProcessor:
                     p, d.sweeps, d.converged, "distributed",
                     work_sweeps=int(d.query_sweeps.sum()))
                 extra["dist"] = d
+            if dist:
+                sp.attrs.update(extra["dist"].span_attrs())
         with obs.span("run.fetch"):
             values = np.stack([post(p.from_blocks(x[q]))
                                for q in range(len(sources))])

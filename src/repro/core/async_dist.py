@@ -67,7 +67,7 @@ from . import semiring as sr
 from .engine import Prepared, _apply
 from .. import resilience
 from .placement import (DistStats, ShardedBatch,  # noqa: F401 (re-export)
-                        _spmv_ref, shard_batched_inputs)
+                        local_spmv, rows_per_step, shard_batched_inputs)
 
 
 def distributed_async_run_batched(
@@ -115,6 +115,7 @@ def distributed_async_run_batched(
     resilience.fire("dist.dispatch", flavor="async", batched=True,
                     shards=d_g)
     rl = sb.r_pad // d_g            # local rows per "graph" shard
+    step = rows_per_step(rl, p.gb)
     ring = sr.get(p.semiring)
     inv_n = jnp.float32(1.0 / max(p.n, 1))
     damping = jnp.float32(damping)
@@ -141,9 +142,8 @@ def distributed_async_run_batched(
         # boundary rows read garbage through the clip and are masked out
         cols_rel = jnp.clip(cols_l - row0, 0, max(rl - 1, 0))
 
-        spmv = jax.vmap(lambda cols, xq: _spmv_ref(
-            vals_l, cols, nnz_l, xq, semiring=p.semiring),
-            in_axes=(None, 0))
+        def spmv(cols, xg):
+            return local_spmv(vals_l, cols, nnz_l, xg, p.semiring, step)
 
         def gather_halo(x):
             # tiled all_gather along "graph": two buffers per round so
@@ -218,8 +218,8 @@ def distributed_async_run_batched(
                 jax.lax.psum(sls, "query")[None])
 
     x, sweeps_q, done_q, exch, shard_sweeps = run(
-        jnp.asarray(sb.vals), jnp.asarray(sb.cols), jnp.asarray(sb.nnz),
-        jnp.asarray(sb.valid), jnp.asarray(sb.x0), jnp.asarray(sb.qlive))
+        sb.vals, sb.cols, sb.nnz, sb.valid, jnp.asarray(sb.x0),
+        jnp.asarray(sb.qlive))
     sweeps_q = np.asarray(sweeps_q)[:Q]
     stats = DistStats(
         sweeps=int(sweeps_q.max(initial=0)),

@@ -37,9 +37,14 @@ from . import semiring as sr
 from .cluster import Clustering, cluster_graph, identity_clustering
 from .graph import Graph, to_bsr
 from ..kernels import ops
-from ..kernels.bsr_spmv import lane_tiles
+from ..kernels.bsr_spmv import LANES, lane_tiles
 from ..kernels.spec import KernelSpec, as_kernel_spec
 from .. import obs, resilience
+
+#: the widest wave of sources a served plan runs at once: the default of
+#: ``GraphService.max_wave`` and ``WavePolicy.max_wave``, and the wave a
+#: plan placed over several devices is sized for when no server says
+MAX_WAVE = 64
 
 
 @dataclasses.dataclass
@@ -90,10 +95,30 @@ class Prepared:
         arrays report nbytes without a device-to-host transfer."""
         dev = sum(int(getattr(self, f).nbytes)
                   for f in _PREPARED_DEVICE_FIELDS)
-        host = int(self.perm.nbytes) + int(self.inv_perm.nbytes) + \
+        return dev + self._host_nbytes()
+
+    @property
+    def shard_nbytes(self) -> int:
+        """``nbytes`` as one device holds it: the device arrays' bytes on
+        the fullest device (a plan whose rows are sharded over a mesh
+        holds a share on each), plus the host metadata.  Equal to
+        ``nbytes`` for a plan on one device."""
+        per: dict = {}
+        for f in _PREPARED_DEVICE_FIELDS:
+            a = getattr(self, f)
+            s = getattr(a, "sharding", None)
+            if s is None:
+                per[None] = per.get(None, 0) + int(a.nbytes)
+                continue
+            n = int(np.prod(s.shard_shape(a.shape))) * a.dtype.itemsize
+            for d in s.device_set:
+                per[d] = per.get(d, 0) + n
+        return max(per.values()) + self._host_nbytes()
+
+    def _host_nbytes(self) -> int:
+        return int(self.perm.nbytes) + int(self.inv_perm.nbytes) + \
             int(self.clustering.assign.nbytes) + \
             int(self.clustering.perm.nbytes)
-        return dev + host
 
 
 # ``Prepared`` as a pytree: device arrays are leaves, host metadata is the
@@ -254,18 +279,27 @@ def deserialize_prepared(data: bytes) -> Prepared:
 def prepare(g: Graph, semiring_name: str, b: int = 32,
             num_clusters: Optional[int] = None, pull: bool = True,
             clustered: bool = True, normalize: Optional[str] = None,
-            seed: int = 0) -> Prepared:
+            seed: int = 0, devices: int = 1,
+            max_wave: int = MAX_WAVE) -> Prepared:
     """Paper Fig. 4 steps 1–5: profile/extract → cluster → analyze →
     place → build the device BSR image.
 
     pull=True computes over in-edges (y_i = ⊕_j A[j→i] ⊗ x_j), the natural
     direction for relaxation/propagation algorithms.
     normalize="out_stochastic": edge j→i gets weight 1/outdeg(j) (PageRank).
+    devices > 1: the plan serves the distributed engines on that many
+    devices; its rows go straight from the host to their shards of a
+    ``("graph", "query")`` mesh that ``placement.plan_mesh`` chooses
+    from the plan's bytes, one device's memory and ``max_wave``, the
+    widest wave it serves.  No device receives the whole plan unless the
+    mesh's "graph" extent is 1.
 
     The phases are timed as spans (``repro.obs``): ``plan.cluster``,
     ``plan.tile`` (permute, transpose, BSR build, lane-tile padding,
     group and halo geometry) and ``plan.upload``, which ends when the
-    plan's arrays are on the device.
+    plan's arrays are on the device; ``plan.upload`` records ``devices``,
+    ``mesh`` and ``shard_bytes`` (tile bytes on the fullest device), and
+    on a mesh holds the ``plan.place`` span of the rows' placement.
     """
     ring = sr.get(semiring_name)
     n = g.n
@@ -311,6 +345,7 @@ def prepare(g: Graph, semiring_name: str, b: int = 32,
         group_edges = np.zeros(s, dtype=np.float64)
         edge_nnz = np.zeros(r_pad, dtype=np.float64)
         edge_nnz[: bsr.r] = bsr.edge_nnz
+        del bsr             # its tiles live on as their padded copy, vals
         np.add.at(group_edges, grp, edge_nnz)
         # halo: tiles whose source col-block lives outside the group row range
         ext = ((cols // gb) != grp[:, None]) & \
@@ -321,22 +356,64 @@ def prepare(g: Graph, semiring_name: str, b: int = 32,
         perm = np.asarray(c.perm)
         inv_perm = np.argsort(perm)
 
-    with obs.span("plan.upload"):
-        dev = dict(
-            vals=jnp.asarray(vals), cols=jnp.asarray(cols),
-            nnz=jnp.asarray(nnz), valid=jnp.asarray(valid),
-            dangling=jnp.asarray(dangling),
-            group_tiles=jnp.asarray(group_tiles, jnp.float32),
-            group_edges=jnp.asarray(group_edges, jnp.float32),
-            group_ext_tiles=jnp.asarray(group_ext_tiles, jnp.float32),
-            row_edges=jnp.asarray(edge_nnz, jnp.float32),
-            row_ext=jnp.asarray(row_ext, jnp.float32))
-        jax.block_until_ready(dev)
+    if devices > 1:
+        dev = _upload_sharded(
+            dict(vals=vals, cols=cols, nnz=nnz, valid=valid,
+                 dangling=dangling,
+                 row_edges=edge_nnz.astype(np.float32),
+                 row_ext=row_ext.astype(np.float32)),
+            dict(group_tiles=group_tiles.astype(np.float32),
+                 group_edges=group_edges.astype(np.float32),
+                 group_ext_tiles=group_ext_tiles.astype(np.float32)),
+            gb, devices, max_wave)
+    else:
+        with obs.span("plan.upload", devices=1, mesh="1x1",
+                      shard_bytes=int(vals.nbytes)):
+            dev = dict(
+                vals=jnp.asarray(vals), cols=jnp.asarray(cols),
+                nnz=jnp.asarray(nnz), valid=jnp.asarray(valid),
+                dangling=jnp.asarray(dangling),
+                group_tiles=jnp.asarray(group_tiles, jnp.float32),
+                group_edges=jnp.asarray(group_edges, jnp.float32),
+                group_ext_tiles=jnp.asarray(group_ext_tiles, jnp.float32),
+                row_edges=jnp.asarray(edge_nnz, jnp.float32),
+                row_ext=jnp.asarray(row_ext, jnp.float32))
+            jax.block_until_ready(dev)
     return Prepared(
         **dev, n=n, b=b, r_pad=r_pad, k_max=k, gb=gb, s=s,
         semiring=semiring_name, perm=perm, inv_perm=inv_perm,
         clustering=c, tiles_total=float(nnz.sum()),
         edges_total=float(edge_nnz.sum()))
+
+
+def _upload_sharded(rows: dict, groups: dict, gb: int, devices: int,
+                    max_wave: int) -> dict:
+    """A plan's device arrays on the mesh ``placement.plan_mesh`` chooses
+    for it: the row arrays sharded ``P("graph")``, the per-group ones
+    replicated."""
+    from jax.sharding import NamedSharding, PartitionSpec
+    from . import placement
+    vals = rows["vals"]
+    r_pad, b = vals.shape[0], vals.shape[1]
+    k = rows["cols"].shape[1]
+    lanes = -(-b // LANES) * LANES    # a row of b floats as the TPU lays it
+    mesh = placement.plan_mesh(
+        r_pad, row_bytes=sum(a.nbytes for a in rows.values()) / r_pad,
+        group_rows=gb,
+        # per query: the gathered source blocks x[cols] of a row of the
+        # sweep's step, and the gathered frontier and the new state
+        query_row_bytes=k * lanes * 4, query_bytes=2 * r_pad * lanes * 4,
+        num_devices=devices, max_wave=max_wave)
+    d_g = dict(mesh.shape)["graph"]
+    with obs.span("plan.upload", devices=devices,
+                  mesh=placement.mesh_name(mesh),
+                  shard_bytes=-(-r_pad // d_g) * int(vals[0].nbytes)):
+        dev = placement.place_rows(rows, mesh)
+        whole = NamedSharding(mesh, PartitionSpec())
+        dev.update({f: jax.device_put(a, whole)
+                    for f, a in groups.items()})
+        jax.block_until_ready(dev)
+    return dev
 
 
 # ---------------------------------------------------------------------------

@@ -31,7 +31,7 @@ from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
 from . import semiring as sr
 from .engine import Prepared, _apply
-from .. import resilience
+from .. import obs, resilience
 from ..kernels import ops
 from ..kernels.spec import KernelSpec
 
@@ -76,6 +76,139 @@ def factor_query_axis(num_devices: int, num_queries: int) -> int:
     return 1
 
 
+# -- plan placement: chosen once, when the plan is built ---------------------
+
+#: share of one device's ``bytes_limit`` that a plan's rows on it plus
+#: the working set of its widest wave may take.  The quarter left over
+#: is for what :func:`device_need` does not count: the compiled sweep's
+#: other buffers, each wave's inputs and results, other plans in the
+#: store and the allocator's fragmentation.
+HEADROOM = 0.75
+
+
+def device_bytes_limit() -> Optional[int]:
+    """The first device's ``bytes_limit``, or None where the backend
+    reports none (the CPU)."""
+    return (jax.devices()[0].memory_stats() or {}).get("bytes_limit")
+
+
+def device_need(rows: int, row_bytes: float, group_rows: int,
+                query_row_bytes: float, query_bytes: float,
+                num_devices: int, max_wave: int, d_g: int) -> float:
+    """Bytes one device holds with the plan on a mesh of "graph" extent
+    ``d_g``: its share of the plan's ``rows`` row-blocks (``row_bytes``
+    each), and for each of the wave's queries it serves, the batched
+    sweep's working set.  The sweep steps over the device's rows
+    :func:`rows_per_step` (at most ``group_rows``) at a time, so a query
+    holds the gathered source blocks of one step's rows
+    (``query_row_bytes`` a row) and its frontier (``query_bytes``)."""
+    local = -(-rows // d_g)
+    queries = -(-max(int(max_wave), 1) // (num_devices // d_g))
+    step = rows_per_step(local, group_rows)
+    return local * row_bytes + \
+        queries * (step * query_row_bytes + query_bytes)
+
+
+def graph_extent(rows: int, row_bytes: float, group_rows: int,
+                 query_row_bytes: float, query_bytes: float,
+                 num_devices: int, max_wave: int,
+                 bytes_limit: Optional[int]) -> int:
+    """The "graph" extent of the mesh a plan of ``rows`` row-blocks is
+    placed on over ``num_devices`` devices.
+
+    The smallest divisor of ``num_devices``, and no smaller than
+    ``factor_query_axis`` leaves for a wave of ``max_wave`` queries, at
+    which :func:`device_need` fits ``HEADROOM`` of ``bytes_limit``
+    (None: no limit).  Where none fits, every device takes a share.
+    """
+    n = int(num_devices)
+    budget = HEADROOM * bytes_limit if bytes_limit else float("inf")
+    low = n // factor_query_axis(n, max(int(max_wave), 1))
+    for d_g in range(low, n + 1):
+        if n % d_g:
+            continue
+        if device_need(rows, row_bytes, group_rows, query_row_bytes,
+                       query_bytes, n, max_wave, d_g) <= budget:
+            break
+    return d_g
+
+
+def plan_mesh(rows: int, row_bytes: float, group_rows: int,
+              query_row_bytes: float, query_bytes: float,
+              num_devices: int, max_wave: int) -> Mesh:
+    """The mesh a plan is placed on, from what can be observed: the
+    device count, the plan's bytes and one device's ``bytes_limit``
+    (:func:`graph_extent`); the devices left over form the "query"
+    extent."""
+    n = int(num_devices)
+    d_g = graph_extent(rows, row_bytes, group_rows, query_row_bytes,
+                       query_bytes, n, max_wave, device_bytes_limit())
+    return make_graph_mesh(n, n // d_g)
+
+
+def mesh_name(mesh: Mesh) -> str:
+    """``"<graph>x<query>"``, as spans record a mesh."""
+    shape = dict(mesh.shape)
+    return f"{shape['graph']}x{shape.get('query', 1)}"
+
+
+def _rows_of(a: np.ndarray, rows: int, index: tuple) -> np.ndarray:
+    """One shard's rows of a host array padded to ``rows`` rows: zero
+    rows past its end."""
+    lo, hi, _ = index[0].indices(rows)
+    return _pad_rows(a[lo:min(hi, a.shape[0])], hi - lo)
+
+
+def place_rows(arrays: dict, mesh: Mesh) -> dict:
+    """Put plan row arrays on ``mesh``, sharded ``P("graph")``, their rows
+    padded with zeros to a multiple of the "graph" extent (a padding row
+    has no tiles and no valid vertex).  Each device receives only its own
+    rows, copied from the host array one shard at a time, so the host
+    stages one shard's transfer, not the whole array's; a device array is
+    first fetched to the host.  One ``plan.place`` span covers it."""
+    d_g = dict(mesh.shape)["graph"]
+    rows = NamedSharding(mesh, P("graph"))
+    out = {}
+    with obs.span("plan.place", devices=mesh.size, mesh=mesh_name(mesh)):
+        for name, a in arrays.items():
+            a = np.asarray(a)
+            shape = (-(-a.shape[0] // d_g) * d_g,) + a.shape[1:]
+            shards = []
+            for d, idx in rows.addressable_devices_indices_map(
+                    shape).items():
+                shards.append(jax.device_put(
+                    _rows_of(a, shape[0], idx), d).block_until_ready())
+            out[name] = jax.make_array_from_single_device_arrays(
+                shape, rows, shards)
+    return out
+
+
+def plan_mesh_of(p: Prepared) -> Optional[Mesh]:
+    """The mesh a plan's rows were placed on at build, or None for a plan
+    on one device."""
+    s = p.vals.sharding
+    if isinstance(s, NamedSharding) and "graph" in s.mesh.axis_names:
+        return s.mesh
+    return None
+
+
+def plan_rows(p: Prepared, mesh: Mesh) -> Tuple[jax.Array, ...]:
+    """``(vals, cols, nnz, valid)`` as a dispatch on ``mesh`` takes them:
+    sharded ``P("graph")``, rows padded to a multiple of the "graph"
+    extent.  A plan placed so at build is used as it is; any other is
+    placed now (:func:`place_rows`)."""
+    names = ("vals", "cols", "nnz", "valid")
+    d_g = dict(mesh.shape)["graph"]
+    r_pad = -(-p.r_pad // d_g) * d_g
+    want = NamedSharding(mesh, P("graph"))
+    arrays = {f: getattr(p, f) for f in names}
+    if not all(isinstance(a, jax.Array) and a.shape[0] == r_pad
+               and a.sharding.is_equivalent_to(want, a.ndim)
+               for a in arrays.values()):
+        arrays = place_rows(arrays, mesh)
+    return tuple(arrays[f] for f in names)
+
+
 @dataclasses.dataclass
 class DistStats:
     sweeps: int
@@ -95,6 +228,17 @@ class DistStats:
     #                                            rate of each shard)
     plan_devices: int = 1       # devices holding a shard of the plan rows
     plan_shard_rows: int = 0    # plan rows resident on each of them
+
+    @property
+    def halo_bytes(self) -> float:
+        """The run's all_gather payload per device: every exchange's."""
+        return self.halo_bytes_per_sweep * self.halo_exchanges
+
+    def span_attrs(self) -> dict:
+        """What a ``run.device`` span records of a distributed run."""
+        return dict(mesh="x".join(map(str, self.mesh_shape)),
+                    halo_exchanges=int(self.halo_exchanges),
+                    halo_bytes=float(self.halo_bytes))
 
 
 def _pad_rows(arr: np.ndarray, rows: int) -> np.ndarray:
@@ -152,10 +296,12 @@ def shard_batched_inputs(p: Prepared, x0: jnp.ndarray,
     Rows are padded to a multiple of the "graph" extent (min-semiring
     padding rows hold +inf so they never win a reduction), queries to a
     multiple of the "query" extent (padding queries are marked dead in
-    ``qlive`` — converged from sweep 0, zero work).  ``query_axis=None``
-    auto-factors the device count against the batch size; 0 is rejected
-    here for every flavor (the per-source escape hatch lives in the
-    session API, not the engines).
+    ``qlive`` — converged from sweep 0, zero work).  A plan placed on a
+    mesh at build runs on that mesh and its rows as they lie
+    (:func:`plan_rows`); otherwise ``query_axis=None`` auto-factors the
+    device count against the batch size.  0 is rejected here for every
+    flavor (the per-source escape hatch lives in the session API, not
+    the engines).
     """
     Q = int(x0.shape[0])
     if query_axis is not None and query_axis < 1:
@@ -166,6 +312,8 @@ def shard_batched_inputs(p: Prepared, x0: jnp.ndarray,
             "batched distributed engines need query_axis=None (auto) "
             f"or >= 1, got {query_axis}; the query_axis=0 per-source "
             "loop is dispatched by the session API, not the engine")
+    if mesh is None and query_axis is None:
+        mesh = plan_mesh_of(p)
     if mesh is None:
         ndev = len(jax.devices())
         mesh = make_graph_mesh(
@@ -175,11 +323,7 @@ def shard_batched_inputs(p: Prepared, x0: jnp.ndarray,
     d_q = shape.get("query", 1)
 
     r_pad = ((p.r_pad + d_g - 1) // d_g) * d_g
-    # each device receives only its own rows of the plan
-    rows = NamedSharding(mesh, P("graph"))
-    vals, cols, nnz, valid = (
-        jax.device_put(_pad_rows(np.asarray(a), r_pad), rows)
-        for a in (p.vals, p.cols, p.nnz, p.valid))
+    vals, cols, nnz, valid = plan_rows(p, mesh)
     q_pad = ((Q + d_q - 1) // d_q) * d_q
     x0 = np.asarray(x0)
     x0 = np.concatenate(
@@ -200,8 +344,9 @@ def distributed_sync_run(
         p: Prepared, x0: jnp.ndarray, apply_kind: str = "relax",
         damping: float = 0.85, tol: float = 1e-6, max_sweeps: int = 10_000,
         mesh: Optional[Mesh] = None) -> Tuple[jnp.ndarray, DistStats]:
-    """Bulk-synchronous distributed engine (shard_map over 'graph')."""
-    mesh = mesh or make_graph_mesh()
+    """Bulk-synchronous distributed engine (shard_map over 'graph'), on
+    the mesh the plan was placed on at build unless ``mesh`` is given."""
+    mesh = mesh or plan_mesh_of(p) or make_graph_mesh()
     d = mesh.shape["graph"]
     # host-level fault sites: an exchange-round failure (raise) and a
     # straggling shard (delay) — shard_map bodies are compiled, so the
@@ -213,10 +358,8 @@ def distributed_sync_run(
     ring = sr.get(p.semiring)
 
     r_pad = ((p.r_pad + d - 1) // d) * d
-    vals = _pad_rows(np.asarray(p.vals), r_pad)
-    cols = _pad_rows(np.asarray(p.cols), r_pad)
-    nnz = _pad_rows(np.asarray(p.nnz), r_pad)
-    valid = _pad_rows(np.asarray(p.valid), r_pad)
+    step = rows_per_step(r_pad // d, p.gb)
+    vals, cols, nnz, valid = plan_rows(p, mesh)
     x0 = _pad_rows(np.asarray(x0), r_pad).copy()
     # padding rows hold the ⊕-identity so they never win a reduction
     x0[p.r_pad:] = ring.zero
@@ -237,7 +380,8 @@ def distributed_sync_run(
         def body(st):
             i, x_loc, _ = st
             xg = jax.lax.all_gather(x_loc, "graph", tiled=True)
-            y = _spmv_ref(vals_l, cols_l, nnz_l, xg, semiring=p.semiring)
+            y = local_spmv(vals_l, cols_l, nnz_l, xg[None], p.semiring,
+                           step)[0]
             x_new, imp = _apply(apply_kind, ring, y, x_loc, valid_l,
                                 damping, inv_n, tol)
             done = ~(jax.lax.psum(jnp.any(imp).astype(jnp.int32),
@@ -248,8 +392,7 @@ def distributed_sync_run(
             cond, body, (jnp.int32(0), x_l, False))
         return x_loc, i[None], done[None]
 
-    x, i, done = run(jnp.asarray(vals), jnp.asarray(cols), jnp.asarray(nnz),
-                     jnp.asarray(valid), jnp.asarray(x0))
+    x, i, done = run(vals, cols, nnz, valid, jnp.asarray(x0))
     halo = (r_pad // d) * p.b * 4.0 * (d - 1)  # gathered remote bytes/device
     stats = DistStats(sweeps=int(i[0]), converged=bool(done[0]),
                       halo_bytes_per_sweep=float(halo),
@@ -257,6 +400,91 @@ def distributed_sync_run(
                       mesh_shape=(d, dict(mesh.shape).get("query", 1)),
                       halo_exchanges=int(i[0]))  # BSP: one per sweep
     return x[: p.r_pad], stats
+
+
+def rows_per_step(local_rows: int, gb: int) -> int:
+    """Rows of the tiles a distributed sweep relaxes at a time: the
+    largest divisor of a device's ``local_rows`` not above the plan's
+    group size ``gb``, as the single-device engines step over groups."""
+    for step in range(min(max(int(gb), 1), local_rows), 0, -1):
+        if local_rows % step == 0:
+            return step
+    return 1
+
+
+def local_spmv(vals, cols, nnz, x, semiring: str, step: int):
+    """``_spmv_ref`` of a device's rows for each query of ``x`` (Q, C, B)
+    → (Q, R, B), ``step`` rows of the tiles at a time: only one step's
+    gathered source blocks are live, not those of every row (at a
+    scale-17 Kronecker plan's shard, 26 GB for a wave of 8 once laid out
+    on the device).  Each row is computed as in one call over all rows,
+    so the result is the same."""
+    r = vals.shape[0]
+
+    def rows(v, c, n):
+        return jax.vmap(lambda xq: _spmv_ref(v, c, n, xq,
+                                             semiring=semiring))(x)
+
+    if step >= r:
+        return rows(vals, cols, nnz)
+    g = r // step
+    y = jax.lax.map(lambda a: rows(*a), (
+        vals.reshape((g, step) + vals.shape[1:]),
+        cols.reshape(g, step, cols.shape[1]), nnz.reshape(g, step)))
+    return jnp.moveaxis(y, 0, 1).reshape(x.shape[0], r, y.shape[-1])
+
+
+@functools.lru_cache(maxsize=64)
+def batched_sync_program(mesh: Mesh, semiring: str, apply_kind: str,
+                         damping: float, inv_n: float, tol: float,
+                         max_sweeps: int, step: int):
+    """The bulk-synchronous batched engine as one jitted shard_map
+    program over ``mesh``: ``(vals, cols, nnz, valid, x0, qlive)`` →
+    ``(x, per-query sweeps, per-query done)``, plan rows sharded
+    ``P("graph")`` and the frontier ``P("query", "graph")``.  Cached, so
+    every wave of a plan dispatches the program compiled for the first."""
+    ring = sr.get(semiring)
+    inv_n = jnp.float32(inv_n)
+    damping = jnp.float32(damping)
+    tol = jnp.float32(tol)
+
+    @functools.partial(
+        jax.shard_map, mesh=mesh,
+        in_specs=(P("graph"), P("graph"), P("graph"), P("graph"),
+                  P("query", "graph"), P("query")),
+        out_specs=(P("query", "graph"), P("query"), P("query")),
+        check_vma=False)
+    def run(vals_l, cols_l, nnz_l, valid_l, x_l, qlive_l):
+        def cond(st):
+            i, x, done_q, sweeps_q, all_done = st
+            return (~all_done) & (i < max_sweeps)
+
+        def body(st):
+            i, x, done_q, sweeps_q, _ = st
+            # halo exchange: ONLY along "graph" — queries are independent
+            xg = jax.lax.all_gather(x, "graph", axis=1, tiled=True)
+            y = local_spmv(vals_l, cols_l, nnz_l, xg, semiring, step)
+            x_new, imp = _apply(apply_kind, ring, y, x, valid_l[None],
+                                damping, inv_n, tol)
+            live = ~done_q
+            # a live query's final (no-improvement) sweep still writes
+            # x_new and counts — exactly like the sequential while_loop
+            x = jnp.where(live[:, None, None], x_new, x)
+            sweeps_q = sweeps_q + live.astype(jnp.int32)
+            imp_q = jax.lax.psum(
+                jnp.any(imp, axis=(1, 2)).astype(jnp.int32), "graph") > 0
+            done_q = done_q | ~imp_q
+            # scalar convergence vote — the only cross-"query" traffic
+            open_n = jax.lax.psum(jnp.sum(~done_q), "query")
+            return i + 1, x, done_q, sweeps_q, open_n == 0
+
+        done0 = ~qlive_l
+        st = (jnp.int32(0), x_l, done0,
+              jnp.zeros(x_l.shape[0], jnp.int32), jnp.array(False))
+        _, x, done_q, sweeps_q, _ = jax.lax.while_loop(cond, body, st)
+        return x, sweeps_q, done_q
+
+    return jax.jit(run)
 
 
 def distributed_sync_run_batched(
@@ -278,8 +506,10 @@ def distributed_sync_run_batched(
     distributed engine, for any mesh factorization.
 
     ``query_axis``: explicit "query" extent (must divide the device
-    count); None auto-factors via :func:`factor_query_axis`.  Ignored
-    when ``mesh`` is given.
+    count); None runs on the mesh the plan was placed on at build, or
+    auto-factors via :func:`factor_query_axis` for a plan on one device.
+    Ignored when ``mesh`` is given.  A device steps over its rows
+    :func:`rows_per_step` at a time (:func:`local_spmv`).
     """
     sb = shard_batched_inputs(p, x0, mesh=mesh, query_axis=query_axis)
     Q, d_g, d_q = sb.q, sb.d_g, sb.d_q
@@ -287,53 +517,12 @@ def distributed_sync_run_batched(
                     shards=d_g)
     resilience.fire("dist.dispatch", flavor="sync", batched=True,
                     shards=d_g)
-    ring = sr.get(p.semiring)
-    inv_n = jnp.float32(1.0 / max(p.n, 1))
-    damping = jnp.float32(damping)
-    tol = jnp.float32(tol)
-
-    @functools.partial(
-        jax.shard_map, mesh=sb.mesh,
-        in_specs=(P("graph"), P("graph"), P("graph"), P("graph"),
-                  P("query", "graph"), P("query")),
-        out_specs=(P("query", "graph"), P("query"), P("query")),
-        check_vma=False)
-    def run(vals_l, cols_l, nnz_l, valid_l, x_l, qlive_l):
-        spmv = jax.vmap(lambda xq: _spmv_ref(vals_l, cols_l, nnz_l, xq,
-                                             semiring=p.semiring))
-
-        def cond(st):
-            i, x, done_q, sweeps_q, all_done = st
-            return (~all_done) & (i < max_sweeps)
-
-        def body(st):
-            i, x, done_q, sweeps_q, _ = st
-            # halo exchange: ONLY along "graph" — queries are independent
-            xg = jax.lax.all_gather(x, "graph", axis=1, tiled=True)
-            y = spmv(xg)
-            x_new, imp = _apply(apply_kind, ring, y, x, valid_l[None],
-                                damping, inv_n, tol)
-            live = ~done_q
-            # a live query's final (no-improvement) sweep still writes
-            # x_new and counts — exactly like the sequential while_loop
-            x = jnp.where(live[:, None, None], x_new, x)
-            sweeps_q = sweeps_q + live.astype(jnp.int32)
-            imp_q = jax.lax.psum(
-                jnp.any(imp, axis=(1, 2)).astype(jnp.int32), "graph") > 0
-            done_q = done_q | ~imp_q
-            # scalar convergence vote — the only cross-"query" traffic
-            open_n = jax.lax.psum(jnp.sum(~done_q), "query")
-            return i + 1, x, done_q, sweeps_q, open_n == 0
-
-        done0 = ~qlive_l
-        st = (jnp.int32(0), x_l, done0,
-              jnp.zeros(x_l.shape[0], jnp.int32), jnp.array(False))
-        _, x, done_q, sweeps_q, _ = jax.lax.while_loop(cond, body, st)
-        return x, sweeps_q, done_q
-
-    x, sweeps_q, done_q = run(
-        jnp.asarray(sb.vals), jnp.asarray(sb.cols), jnp.asarray(sb.nnz),
-        jnp.asarray(sb.valid), jnp.asarray(sb.x0), jnp.asarray(sb.qlive))
+    run = batched_sync_program(
+        sb.mesh, p.semiring, apply_kind, damping=damping,
+        inv_n=1.0 / max(p.n, 1), tol=tol, max_sweeps=max_sweeps,
+        step=rows_per_step(sb.r_pad // d_g, p.gb))
+    x, sweeps_q, done_q = run(sb.vals, sb.cols, sb.nnz, sb.valid,
+                              jnp.asarray(sb.x0), jnp.asarray(sb.qlive))
     sweeps_q = np.asarray(sweeps_q)[:Q]
     straggler = int(sweeps_q.max(initial=0))
     stats = DistStats(

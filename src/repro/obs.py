@@ -24,7 +24,10 @@ An operator reads the spans with ``spans()``:
 The names in use: ``request.queue``, ``wave.launch``, ``wave``,
 ``wave.resolve`` (``serve/sched.py``), ``run.prep``, ``run.device``,
 ``run.fetch`` (``core/api.py``), ``plan.cluster``, ``plan.tile``,
-``plan.upload`` (``core/engine.prepare``) and ``jax.compile``.
+``plan.upload`` (``core/engine.prepare``), ``plan.place`` (every
+placement of plan rows onto a mesh, ``core/placement.place_rows``: in
+the ``plan.upload`` of a sharded plan, and wherever a plan would move)
+and ``jax.compile``.
 """
 
 from __future__ import annotations

@@ -111,11 +111,16 @@ class PlanStore:
     """Bounded LRU of ``Prepared`` images with a persistent disk tier.
 
     Memory tier: an ordered map ``(fingerprint, PlanKey) → Prepared``
-    with byte-size accounting (``Prepared.nbytes``); inserting past
-    ``max_bytes`` evicts least-recently-used plans.  Disk tier (optional
-    ``cache_dir``): every built plan is serialized on ``put``; a memory
-    miss falls through to disk before reporting a miss, so evicted and
-    cross-process plans reload without re-running the compile pipeline.
+    with byte-size accounting; inserting past ``max_bytes`` evicts
+    least-recently-used plans.  ``max_bytes`` is one device's budget, so
+    a plan is charged its bytes on its fullest device
+    (``Prepared.shard_nbytes``): a plan sharded over a mesh costs its
+    share.  Disk tier (optional ``cache_dir``): every built plan on one
+    device is serialized on ``put``; a memory miss falls through to disk
+    before reporting a miss, so evicted and cross-process plans reload
+    without re-running the compile pipeline.  Sharded plans
+    (``PlanKey.devices > 1``) stay in memory: a reload would put the
+    whole plan on one device.
     """
 
     def __init__(self, max_bytes: int = 256 << 20,
@@ -172,7 +177,7 @@ class PlanStore:
 
     def put(self, fingerprint: str, key: PlanKey, p: Prepared) -> None:
         path = payload = None
-        if self.cache_dir:
+        if self.cache_dir and key.devices == 1:
             path = os.path.join(self.cache_dir,
                                 _plan_filename(fingerprint, key))
             if not os.path.exists(path):
@@ -213,7 +218,7 @@ class PlanStore:
         if k in self._mem:
             self._bytes -= self._mem[k][1]
             del self._mem[k]
-        nb = p.nbytes
+        nb = p.shard_nbytes
         self._mem[k] = (p, nb)
         self._bytes += nb
         # never evict the entry just inserted: a single plan larger than
@@ -226,7 +231,7 @@ class PlanStore:
 
     def _load_disk(self, fingerprint: str,
                    key: PlanKey) -> Optional[Prepared]:
-        if not self.cache_dir:
+        if not self.cache_dir or key.devices > 1:
             return None
         path = os.path.join(self.cache_dir,
                             _plan_filename(fingerprint, key))
@@ -443,7 +448,7 @@ class GraphService:
     def __init__(self, max_plan_bytes: int = 256 << 20,
                  cache_dir: Optional[str] = None,
                  policy: Optional[ExecutionPolicy] = None,
-                 max_wave: int = 64):
+                 max_wave: int = eng.MAX_WAVE):
         self.store = PlanStore(max_bytes=max_plan_bytes,
                                cache_dir=cache_dir)
         self.policy = policy
@@ -489,7 +494,7 @@ class GraphService:
             proc = GraphProcessor(
                 g, b=b, num_clusters=num_clusters, clustered=clustered,
                 seed=seed, policy=policy or self.policy,
-                store=self.store)
+                store=self.store, max_wave=self.max_wave)
             self._procs[name] = proc
             return proc
 
@@ -547,7 +552,8 @@ class GraphService:
         proc = self.get(name)
         a = get_algorithm(algo)
         pk = proc.plan_key(a.semiring, variant=a.variant, pull=a.pull,
-                           normalize=a.normalize)
+                           normalize=a.normalize,
+                           devices=proc.plan_devices(pol))
         p = self.store.peek(proc.g.fingerprint(), pk)
         tiles = float(p.tiles_total) if p is not None \
             else float(proc.g.nnz)

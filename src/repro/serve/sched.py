@@ -61,6 +61,7 @@ from typing import Dict, List, NamedTuple, Optional, Tuple
 
 from .. import obs, resilience
 from ..core.api import QuerySpec
+from ..core.engine import MAX_WAVE
 from .graph import GraphService, _Pending
 
 
@@ -129,7 +130,7 @@ class WavePolicy:
                  while a hung wave winds down.
     """
 
-    max_wave: int = 64
+    max_wave: int = MAX_WAVE
     max_wait_s: float = 0.005
     max_pending: int = 1024
     workers: int = 1

@@ -139,7 +139,8 @@ class GraphServer:
     def _warm_one(self, proc, key) -> None:
         try:
             proc.prepare(key.semiring, variant=key.variant,
-                         pull=key.pull, normalize=key.normalize)
+                         pull=key.pull, normalize=key.normalize,
+                         devices=key.devices)
             with self._lock:
                 self._plans_warmed += 1
         except Exception:

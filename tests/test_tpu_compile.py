@@ -121,3 +121,61 @@ def test_distributed_sweep_compiles(topo, mesh_shape):
     text = c.as_text()
     assert "all-gather" in text
     assert _fits(c)
+
+
+def _s17_program(topo, d_g, q=8):
+    """The g500_s17.bfs_c8 cell's program, the bulk-synchronous batched
+    engine for a wave of ``q``, compiled for a (``d_g``, 4 // ``d_g``)
+    mesh with the scale-17 Kronecker plan (8,192 row-blocks in groups of
+    128, K 3,112, 26.1 GB) row-sharded over "graph"."""
+    from jax.sharding import NamedSharding, PartitionSpec as P
+    r, k = 8192, 3112
+    mesh = Mesh(np.asarray(topo.devices).reshape(d_g, 4 // d_g),
+                ("graph", "query"))
+    rows = NamedSharding(mesh, P("graph"))
+    step = placement.rows_per_step(r // d_g, r // 64)
+    run = placement.batched_sync_program(
+        mesh, "min_plus", "relax", 0.85, 1.0 / (r * B), 1e-6, 10_000,
+        step)
+    return step, run.lower(
+        jax.ShapeDtypeStruct((r, B, k * B), jnp.float32, sharding=rows),
+        jax.ShapeDtypeStruct((r, k), jnp.int32, sharding=rows),
+        jax.ShapeDtypeStruct((r,), jnp.int32, sharding=rows),
+        jax.ShapeDtypeStruct((r, B), jnp.bool_, sharding=rows),
+        jax.ShapeDtypeStruct((q, r, B), jnp.float32, sharding=NamedSharding(
+            mesh, P("query", "graph"))),
+        jax.ShapeDtypeStruct((q,), jnp.bool_, sharding=NamedSharding(
+            mesh, P("query")))).compile()
+
+
+def test_scale_17_graph500_wave_fits_four_chips(topo):
+    """On a (4, 1) mesh a chip holds its 6.5 GB of tiles and the gathered
+    source blocks of one row group at a time
+    (:func:`placement.local_spmv`); over all its 2,048 rows at once they
+    would take 26 GB."""
+    r, k = 8192, 3112
+    step, c = _s17_program(topo, 4)
+    m = c.memory_analysis()
+    assert step == 128
+    assert m.argument_size_in_bytes > (r // 4) * B * k * B * 4
+    assert m.temp_size_in_bytes < 4e9
+    assert "all-gather" in c.as_text()
+    assert _fits(c)
+
+
+@pytest.mark.parametrize("d_g", [4, 2])
+def test_scale_17_device_need_reads_what_the_compiler_gives(topo, d_g):
+    """``placement.device_need``, which chooses the plan's mesh, is within
+    5% of the compiler's bytes for the program's arguments and
+    temporaries on a chip: the plan's rows and the stepped sweep's
+    working set at the lane-padded width."""
+    r, k = 8192, 3112
+    _, c = _s17_program(topo, d_g)
+    m = c.memory_analysis()
+    compiled = m.argument_size_in_bytes + m.temp_size_in_bytes
+    lanes = 128
+    row = B * k * B * 4 + k * 4 + 4 + 2 * B + 8
+    need = placement.device_need(r, row, r // 64, k * lanes * 4,
+                                 2 * r * lanes * 4, num_devices=4,
+                                 max_wave=8, d_g=d_g)
+    assert abs(need - compiled) < 0.05 * compiled
