@@ -661,7 +661,9 @@ class GraphProcessor:
         extra = {"algo": spec.algo, "sources": sources}
         kw = dict(apply_kind=apply_kind, damping=pol.damping, tol=pol.tol,
                   max_sweeps=pol.max_sweeps)
-        wave = not dist and pol.mode == "async" and eng.wave_path(kern)
+        # the distributed engines always run the wave kernel
+        # (placement.local_spmv); one device only the async ref loop
+        wave = dist or (pol.mode == "async" and eng.wave_path(kern))
         with obs.span("run.device", gather="wave" if wave else "per_query",
                       q=len(sources)) as sp:
             if not dist:

@@ -143,7 +143,10 @@ def distributed_async_run_batched(
         cols_rel = jnp.clip(cols_l - row0, 0, max(rl - 1, 0))
 
         def spmv(cols, xg):
-            return local_spmv(vals_l, cols, nnz_l, xg, p.semiring, step)
+            # the wave kernel takes the queries inside each row-block
+            return jnp.swapaxes(local_spmv(
+                vals_l, cols, nnz_l, jnp.swapaxes(xg, 0, 1), p.semiring,
+                step), 0, 1)
 
         def gather_halo(x):
             # tiled all_gather along "graph": two buffers per round so

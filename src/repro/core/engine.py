@@ -37,7 +37,7 @@ from . import semiring as sr
 from .cluster import Clustering, cluster_graph, identity_clustering
 from .graph import Graph, to_bsr
 from ..kernels import ops
-from ..kernels.bsr_spmv import LANES, lane_tiles
+from ..kernels.bsr_spmv import lane_tiles
 from ..kernels.spec import KernelSpec, as_kernel_spec
 from .. import obs, resilience
 
@@ -396,13 +396,14 @@ def _upload_sharded(rows: dict, groups: dict, gb: int, devices: int,
     vals = rows["vals"]
     r_pad, b = vals.shape[0], vals.shape[1]
     k = rows["cols"].shape[1]
-    lanes = -(-b // LANES) * LANES    # a row of b floats as the TPU lays it
     mesh = placement.plan_mesh(
         r_pad, row_bytes=sum(a.nbytes for a in rows.values()) / r_pad,
         group_rows=gb,
-        # per query: the gathered source blocks x[cols] of a row of the
-        # sweep's step, and the gathered frontier and the new state
-        query_row_bytes=k * lanes * 4, query_bytes=2 * r_pad * lanes * 4,
+        # per query, lane-dense (a wave carries its queries inside each
+        # row-block): the gathered source blocks x[cols] of a row of the
+        # sweep's step and their relayout, and the gathered frontier and
+        # the new state
+        query_row_bytes=2 * k * b * 4, query_bytes=2 * r_pad * b * 4,
         num_devices=devices, max_wave=max_wave)
     d_g = dict(mesh.shape)["graph"]
     with obs.span("plan.upload", devices=devices,

@@ -35,10 +35,10 @@ from .. import obs, resilience
 from ..kernels import ops
 from ..kernels.spec import KernelSpec
 
-# the distributed engines shard_map the ref kernel (Pallas calls cannot
-# be SPMD-partitioned); resolved once through the same registry the
-# local engines use
-_spmv_ref = ops.select_kernel("bsr_spmv", KernelSpec(impl="ref"))
+# the distributed engines shard_map the ref wave kernel (Pallas calls
+# cannot be SPMD-partitioned); resolved once through the same registry
+# the local engines use
+_spmv_wave = ops.select_kernel("bsr_spmv_wave", KernelSpec(impl="ref"))
 
 
 def make_graph_mesh(num_devices: Optional[int] = None,
@@ -379,11 +379,10 @@ def distributed_sync_run(
 
         def body(st):
             i, x_loc, _ = st
-            xg = jax.lax.all_gather(x_loc, "graph", tiled=True)
-            y = local_spmv(vals_l, cols_l, nnz_l, xg[None], p.semiring,
-                           step)[0]
-            x_new, imp = _apply(apply_kind, ring, y, x_loc, valid_l,
-                                damping, inv_n, tol)
+            # one query: (r, B) is the wave's row-major layout at Q=1
+            x_new, imp = wave_sweep(vals_l, cols_l, nnz_l, valid_l, x_loc,
+                                    p.semiring, apply_kind, step, damping,
+                                    inv_n, tol)
             done = ~(jax.lax.psum(jnp.any(imp).astype(jnp.int32),
                                   "graph") > 0)
             return i + 1, x_new, done
@@ -413,17 +412,16 @@ def rows_per_step(local_rows: int, gb: int) -> int:
 
 
 def local_spmv(vals, cols, nnz, x, semiring: str, step: int):
-    """``_spmv_ref`` of a device's rows for each query of ``x`` (Q, C, B)
-    → (Q, R, B), ``step`` rows of the tiles at a time: only one step's
-    gathered source blocks are live, not those of every row (at a
-    scale-17 Kronecker plan's shard, 26 GB for a wave of 8 once laid out
-    on the device).  Each row is computed as in one call over all rows,
-    so the result is the same."""
+    """The wave kernel over a device's rows for a wave ``x`` (C, Q, B),
+    queries contiguous inside each row-block → (R, Q, B), ``step`` rows
+    of the tiles at a time: only one step's gathered source rows are
+    live, not those of every row (at a scale-17 Kronecker plan's shard,
+    26 GB for a wave of 8).  Each row is computed as in one call over
+    all rows, so the result is the same."""
     r = vals.shape[0]
 
     def rows(v, c, n):
-        return jax.vmap(lambda xq: _spmv_ref(v, c, n, xq,
-                                             semiring=semiring))(x)
+        return _spmv_wave(v, c, n, x, semiring=semiring)
 
     if step >= r:
         return rows(vals, cols, nnz)
@@ -431,7 +429,24 @@ def local_spmv(vals, cols, nnz, x, semiring: str, step: int):
     y = jax.lax.map(lambda a: rows(*a), (
         vals.reshape((g, step) + vals.shape[1:]),
         cols.reshape(g, step, cols.shape[1]), nnz.reshape(g, step)))
-    return jnp.moveaxis(y, 0, 1).reshape(x.shape[0], r, y.shape[-1])
+    return y.reshape((r,) + y.shape[2:])
+
+
+def wave_sweep(vals, cols, nnz, valid, x, semiring: str, apply_kind: str,
+               step: int, damping, inv_n, tol):
+    """One bulk-synchronous sweep of a device's rows inside a shard_map
+    over "graph", for a wave carried row-major: ``x`` (r, Q*B), query q
+    of row-block i in ``x[i, q*B:(q+1)*B]``.  The halo is a tiled
+    all_gather of ``x`` along "graph"; each tile's source block is then
+    one contiguous row of Q*B values for the whole wave
+    (:func:`local_spmv`).  Returns ``(x_new, improved)``, both (r, Q*B)."""
+    r, qb = x.shape
+    b = valid.shape[1]
+    xg = jax.lax.all_gather(x, "graph", tiled=True)
+    y = local_spmv(vals, cols, nnz, xg.reshape(-1, qb // b, b), semiring,
+                   step)
+    return _apply(apply_kind, sr.get(semiring), y.reshape(r, qb), x,
+                  jnp.tile(valid, (1, qb // b)), damping, inv_n, tol)
 
 
 @functools.lru_cache(maxsize=64)
@@ -443,7 +458,6 @@ def batched_sync_program(mesh: Mesh, semiring: str, apply_kind: str,
     ``(x, per-query sweeps, per-query done)``, plan rows sharded
     ``P("graph")`` and the frontier ``P("query", "graph")``.  Cached, so
     every wave of a plan dispatches the program compiled for the first."""
-    ring = sr.get(semiring)
     inv_n = jnp.float32(inv_n)
     damping = jnp.float32(damping)
     tol = jnp.float32(tol)
@@ -455,6 +469,8 @@ def batched_sync_program(mesh: Mesh, semiring: str, apply_kind: str,
         out_specs=(P("query", "graph"), P("query"), P("query")),
         check_vma=False)
     def run(vals_l, cols_l, nnz_l, valid_l, x_l, qlive_l):
+        nq, r_l, b = x_l.shape
+
         def cond(st):
             i, x, done_q, sweeps_q, all_done = st
             return (~all_done) & (i < max_sweeps)
@@ -462,27 +478,31 @@ def batched_sync_program(mesh: Mesh, semiring: str, apply_kind: str,
         def body(st):
             i, x, done_q, sweeps_q, _ = st
             # halo exchange: ONLY along "graph" — queries are independent
-            xg = jax.lax.all_gather(x, "graph", axis=1, tiled=True)
-            y = local_spmv(vals_l, cols_l, nnz_l, xg, semiring, step)
-            x_new, imp = _apply(apply_kind, ring, y, x, valid_l[None],
-                                damping, inv_n, tol)
+            x_new, imp = wave_sweep(vals_l, cols_l, nnz_l, valid_l, x,
+                                    semiring, apply_kind, step, damping,
+                                    inv_n, tol)
             live = ~done_q
             # a live query's final (no-improvement) sweep still writes
             # x_new and counts — exactly like the sequential while_loop
-            x = jnp.where(live[:, None, None], x_new, x)
+            x = jnp.where(jnp.repeat(live, b)[None], x_new, x)
             sweeps_q = sweeps_q + live.astype(jnp.int32)
-            imp_q = jax.lax.psum(
-                jnp.any(imp, axis=(1, 2)).astype(jnp.int32), "graph") > 0
+            imp_q = jax.lax.psum(jnp.any(
+                imp.reshape(r_l, nq, b), axis=(0, 2)).astype(jnp.int32),
+                "graph") > 0
             done_q = done_q | ~imp_q
             # scalar convergence vote — the only cross-"query" traffic
             open_n = jax.lax.psum(jnp.sum(~done_q), "query")
             return i + 1, x, done_q, sweeps_q, open_n == 0
 
-        done0 = ~qlive_l
-        st = (jnp.int32(0), x_l, done0,
-              jnp.zeros(x_l.shape[0], jnp.int32), jnp.array(False))
+        # the wave is carried row-major, (r_l, Q*B), relaid once on
+        # either side of the loop: a relayout inside it could fuse into
+        # the source gather and read Q strided rows of B again
+        x = jnp.transpose(x_l, (1, 0, 2)).reshape(r_l, nq * b)
+        st = (jnp.int32(0), x, ~qlive_l, jnp.zeros(nq, jnp.int32),
+              jnp.array(False))
         _, x, done_q, sweeps_q, _ = jax.lax.while_loop(cond, body, st)
-        return x, sweeps_q, done_q
+        return (jnp.transpose(x.reshape(r_l, nq, b), (1, 0, 2)), sweeps_q,
+                done_q)
 
     return jax.jit(run)
 
@@ -538,17 +558,19 @@ def distributed_sync_run_batched(
 
 def lower_distributed(p: Prepared, mesh: Mesh, apply_kind: str = "relax",
                       batch: Optional[int] = None):
-    """Lower (no execution) the distributed sweep for dry-run inspection.
+    """Lower (no execution) the distributed sweep for dry-run inspection:
+    the engines' :func:`wave_sweep`, stepping over a device's rows as
+    they do.
 
     ``batch=Q`` lowers the 2-D batched sweep instead: a ``(Q, r_pad, B)``
-    frontier sharded ``P("query", "graph")`` — the collective layout CI
-    and dry-run tooling inspect to confirm the halo exchange stays on
-    "graph"."""
+    frontier sharded ``P("query", "graph")``, carried row-major as the
+    batched engine carries it — the collective layout CI and dry-run
+    tooling inspect to confirm the halo exchange stays on "graph"."""
     shape = dict(mesh.shape)
     d = shape["graph"]
     d_q = shape.get("query", 1)
     r_pad = ((p.r_pad + d - 1) // d) * d
-    ring = sr.get(p.semiring)
+    step = rows_per_step(r_pad // d, p.gb)
     shard = NamedSharding(mesh, P("graph"))
 
     def one_sweep(vals, cols, nnz, valid, x):
@@ -559,19 +581,16 @@ def lower_distributed(p: Prepared, mesh: Mesh, apply_kind: str = "relax",
             out_specs=P("query", "graph") if batch else P("graph"),
             check_vma=False)
         def sweep(vals_l, cols_l, nnz_l, valid_l, x_l):
+            xr = x_l
             if batch:
-                xg = jax.lax.all_gather(x_l, "graph", axis=1, tiled=True)
-                y = jax.vmap(lambda xq: _spmv_ref(
-                    vals_l, cols_l, nnz_l, xq, semiring=p.semiring))(xg)
-                valid_b = valid_l[None]
-            else:
-                xg = jax.lax.all_gather(x_l, "graph", tiled=True)
-                y = _spmv_ref(vals_l, cols_l, nnz_l, xg,
-                              semiring=p.semiring)
-                valid_b = valid_l
-            x_new, _ = _apply(apply_kind, ring, y, x_l, valid_b,
-                              jnp.float32(0.85), jnp.float32(1.0 / p.n),
-                              jnp.float32(1e-6))
+                nq, r_l, b = x_l.shape
+                xr = jnp.transpose(x_l, (1, 0, 2)).reshape(r_l, nq * b)
+            x_new, _ = wave_sweep(vals_l, cols_l, nnz_l, valid_l, xr,
+                                  p.semiring, apply_kind, step,
+                                  jnp.float32(0.85), jnp.float32(1.0 / p.n),
+                                  jnp.float32(1e-6))
+            if batch:
+                x_new = jnp.transpose(x_new.reshape(r_l, nq, b), (1, 0, 2))
             return x_new
         return sweep(vals, cols, nnz, valid, x)
 

@@ -9,6 +9,8 @@ from __future__ import annotations
 import jax
 import jax.numpy as jnp
 
+from .bsr_spmv import LANES
+
 
 # ---------------------------------------------------------------------------
 # bsr_spmv — block-sparse semiring SpMV
@@ -73,13 +75,26 @@ def bsr_spmv_wave_ref(block_vals: jnp.ndarray, block_cols: jnp.ndarray,
     The source gather fetches each tile's block once for the whole wave,
     one contiguous row of Q*B values; Q vectors carried apart would be Q
     strided rows of B values per tile.
+
+    The relayout after the gather puts K on the lanes.  For a K that is
+    no multiple of 128 the TPU compiler does it through a reshape that is
+    no bitcast, at several times the cost per tile slot; so the gather
+    takes whole lane columns of tiles (padding slots read block 0) where
+    that adds at most an eighth to K, and the padding is cut before the
+    ⊕-reduce.
     """
     r = block_vals.shape[0]
     c, q, b = x.shape
     k = block_cols.shape[1]
-    rows = x.reshape(c, q * b)[block_cols]            # (R, K, Q*B)
-    xs = rows.reshape(r, k, q, b).transpose(0, 2, 1, 3).reshape(
-        r, q, 1, k * b)                               # (R, Q, 1, K*B)
+    pad = -k % LANES
+    if pad > k // 8:
+        pad = 0
+    cols = jnp.pad(block_cols, ((0, 0), (0, pad))) if pad else block_cols
+    rows = x.reshape(c, q * b)[cols]                  # (R, K+pad, Q*B)
+    xs = rows.reshape(r, k + pad, q, b).transpose(0, 2, 1, 3)
+    if pad:
+        xs = xs[:, :, :k]
+    xs = xs.reshape(r, q, 1, k * b)                   # (R, Q, 1, K*B)
     if semiring == "plus_times":
         return jnp.einsum("rij,rqj->rqi", block_vals, xs[:, :, 0],
                           precision=jax.lax.Precision.HIGHEST)

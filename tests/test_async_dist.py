@@ -4,11 +4,12 @@ The contract under test: ``dist_flavor="async"`` reaches the SAME
 fixpoint as the bulk-synchronous distributed engine — bit-identical
 converged state on every mesh factorization and every k — while
 ``DistStats.halo_exchanges`` strictly drops for k > 1 on multi-sweep
-fixpoints.  Multi-mesh cases run in-process on the DEVICES=8 CI lane
-(fake host devices) and fall back to one subprocess sweep elsewhere,
-mirroring tests/test_distribution.py.
+fixpoints.  The factorization parity cases read one subprocess on four
+virtual CPU devices (``tests/dist_parity.py``), as
+tests/test_distribution.py does.
 """
 
+import json
 import os
 import subprocess
 import sys
@@ -23,8 +24,11 @@ from repro.core import engine as eng
 from repro.core import graph as G
 from repro.core import placement as PL
 
-# (num_devices, query_axis) — the factorizations the issue names
-FACTORIZATIONS = [(1, 1), (4, 2), (8, 1), (8, 8)]
+ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+
+# (num_devices, query_axis): meshes 1x1, 4x1, 2x2 and 1x4
+FACTORIZATIONS = [(1, 1), (4, 1), (4, 2), (4, 4)]
+SEMIRINGS = ["min_plus", "min_select", "max_min", "plus_times"]
 KS = [1, 2, 4]
 
 
@@ -51,30 +55,36 @@ def _batched_fixture(semiring):
 # -- parity + exchange reduction ----------------------------------------
 
 
-@pytest.mark.parametrize("semiring", ["min_plus", "max_min"])
+@pytest.fixture(scope="module")
+def parity():
+    env = dict(os.environ, PYTHONPATH=os.path.join(ROOT, "src"))
+    run = subprocess.run(
+        [sys.executable, os.path.join(ROOT, "tests", "dist_parity.py"),
+         "async"], capture_output=True, text=True, env=env, cwd=ROOT,
+        timeout=900)
+    lines = [ln for ln in run.stdout.splitlines() if ln.startswith("RESULT")]
+    assert lines, run.stderr[-3000:]
+    return json.loads(lines[-1][len("RESULT"):])
+
+
+@pytest.mark.parametrize("semiring", SEMIRINGS)
 @pytest.mark.parametrize("k", KS)
 @pytest.mark.parametrize("ndev,qaxis", FACTORIZATIONS)
-def test_async_parity_across_factorizations(semiring, k, ndev, qaxis):
-    """Async == sync distributed == run_sync_batched, BIT-identical, on
-    every factorization × k.  Needs the multi-device lane's fake-device
-    grid for the non-trivial meshes."""
-    if len(jax.devices()) < ndev:
-        pytest.skip(f"needs {ndev} devices (CI multi-device lane); "
-                    f"have {len(jax.devices())} — subprocess test "
-                    "covers this elsewhere")
-    p, x0, ref = _batched_fixture(semiring)
-    mesh = PL.make_graph_mesh(ndev, qaxis)
-    x, ds = AD.distributed_async_run_batched(
-        p, x0, max_sweeps=100_000, mesh=mesh, local_sweeps=k)
-    assert np.array_equal(np.asarray(x), ref)
-    assert ds.converged
-    assert ds.mesh_shape == (ndev // qaxis, qaxis)
-    assert ds.local_sweeps == k
-    assert ds.query_sweeps.shape == (x0.shape[0],)
-    assert ds.sweeps == int(ds.query_sweeps.max())
+def test_async_parity_across_factorizations(parity, semiring, k, ndev,
+                                            qaxis):
+    """Async == the per-source sequential (bulk-synchronous) distributed
+    engine, BIT-identical, on every factorization × k, for a wave of 5
+    queries (padding queries on 2x2 and 1x4); on four virtual devices
+    (``tests/dist_parity.py``)."""
+    r = parity[f"{semiring}/{ndev // qaxis}x{qaxis}/k{k}"]
+    assert r["equal"] and r["converged"]
+    assert r["mesh"] == [ndev // qaxis, qaxis]
+    assert r["local_sweeps"] == k
+    assert len(r["query_sweeps"]) == 5
+    assert r["sweeps"] == max(r["query_sweeps"])
     # per-shard self-timed sweep counters, one per "graph" shard
-    assert ds.shard_sweeps.shape == (ndev // qaxis,)
-    assert int(ds.shard_sweeps.max()) >= ds.sweeps
+    assert len(r["shard_sweeps"]) == ndev // qaxis
+    assert max(r["shard_sweeps"]) >= r["sweeps"]
 
 
 @pytest.mark.parametrize("semiring", ["min_plus", "max_min"])

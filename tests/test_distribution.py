@@ -2,11 +2,8 @@
 devices via subprocess), the 2-D ("graph", "query") batched engine
 across mesh factorizations, dry-run cell smoke.
 
-The factorization parity tests run in-process when the host already has
->= 8 devices (the CI multi-device lane sets
-XLA_FLAGS=--xla_force_host_platform_device_count=8 via DEVICES=8 in
-benchmarks/ci.sh) and fall back to one subprocess sweep on single-device
-hosts."""
+The factorization parity tests read one subprocess on four virtual CPU
+devices (``tests/dist_parity.py``); the 8-device checks run in another."""
 
 import json
 import os
@@ -14,7 +11,6 @@ import subprocess
 import sys
 from types import SimpleNamespace
 
-import jax
 import numpy as np
 import pytest
 from jax.sharding import PartitionSpec as P
@@ -107,59 +103,47 @@ def test_batched_engine_rejects_query_axis_0():
     """The query_axis=0 per-source escape hatch is the session API's —
     the engine must refuse it rather than silently auto-factor."""
     from repro.core import placement as PL
-    p, x0, _ = _batched_fixture("min_plus")
+    from repro.core import engine as eng
+    from repro.core import graph as G
+    g = G.rmat(200, 900, seed=6)
+    p = eng.prepare(g, "min_plus", b=8, num_clusters=8)
+    x0 = np.full((2, p.r_pad, p.b), np.inf, np.float32)
     with pytest.raises(ValueError, match="query_axis"):
         PL.distributed_sync_run_batched(p, x0, query_axis=0)
 
 
-def _batched_fixture(semiring):
-    """(Prepared, stacked x0, sync-batched reference) for one semiring."""
-    from repro.core import engine as eng
-    from repro.core import graph as G
+ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 
-    g = G.rmat(200, 900, seed=6)
-    sources = [0, 5, 9, 13, 17]
-    p = eng.prepare(g, semiring, b=8, num_clusters=8)
-    if semiring == "max_min":
-        def x0f(s):
-            x = np.zeros(g.n, dtype=np.float32)
-            x[s] = 1.0
-            return np.asarray(p.to_blocks(x, 0.0))
-    else:
-        def x0f(s):
-            x = np.full(g.n, np.inf, dtype=np.float32)
-            x[s] = 0.0
-            return np.asarray(p.to_blocks(x, np.inf))
-    x0 = np.stack([x0f(s) for s in sources])
-    ref, _ = eng.run_sync_batched(p, x0, max_sweeps=100_000)
-    return p, x0, np.asarray(ref)
+# (num_devices, query_axis): meshes 1x1, 4x1, 2x2 and 1x4
+FACTORIZATIONS = [(1, 1), (4, 1), (4, 2), (4, 4)]
+SEMIRINGS = ["min_plus", "min_select", "max_min", "plus_times"]
 
 
-# (num_devices, query_axis) — the factorizations the issue names
-FACTORIZATIONS = [(1, 1), (4, 2), (8, 1), (8, 8)]
+@pytest.fixture(scope="module")
+def parity():
+    env = dict(os.environ, PYTHONPATH=os.path.join(ROOT, "src"))
+    run = subprocess.run(
+        [sys.executable, os.path.join(ROOT, "tests", "dist_parity.py"),
+         "sync"], capture_output=True, text=True, env=env, cwd=ROOT,
+        timeout=600)
+    lines = [ln for ln in run.stdout.splitlines() if ln.startswith("RESULT")]
+    assert lines, run.stderr[-3000:]
+    return json.loads(lines[-1][len("RESULT"):])
 
 
-@pytest.mark.parametrize("semiring", ["min_plus", "max_min"])
+@pytest.mark.parametrize("semiring", SEMIRINGS)
 @pytest.mark.parametrize("ndev,qaxis", FACTORIZATIONS)
 def test_batched_distributed_parity_across_factorizations(
-        semiring, ndev, qaxis):
-    """Batched-distributed == run_sync_batched, BIT-identical, on every
-    mesh factorization (1×1, 2×2, 8×1, 1×8).  Needs the multi-device
-    lane's fake-device grid for the non-trivial meshes."""
-    if len(jax.devices()) < ndev:
-        pytest.skip(f"needs {ndev} devices (CI multi-device lane); "
-                    f"have {len(jax.devices())} — subprocess test "
-                    "covers this elsewhere")
-    from repro.core import placement as PL
-    p, x0, ref = _batched_fixture(semiring)
-    mesh = PL.make_graph_mesh(ndev, qaxis)
-    x, ds = PL.distributed_sync_run_batched(p, x0, "relax",
-                                            max_sweeps=100_000, mesh=mesh)
-    assert np.array_equal(np.asarray(x), ref)
-    assert ds.converged
-    assert ds.mesh_shape == (ndev // qaxis, qaxis)
-    assert ds.query_sweeps.shape == (x0.shape[0],)
-    assert ds.sweeps == int(ds.query_sweeps.max())
+        parity, semiring, ndev, qaxis):
+    """Batched-distributed == the per-source sequential distributed
+    engine, BIT-identical, with the same per-query sweeps, on every mesh
+    factorization, for a wave of 5 queries (padding queries on 2x2 and
+    1x4)."""
+    r = parity[f"{semiring}/{ndev // qaxis}x{qaxis}"]
+    assert r["equal"] and r["converged"]
+    assert r["mesh"] == [ndev // qaxis, qaxis]
+    assert r["query_sweeps"] == r["ref_sweeps"]
+    assert r["sweeps"] == max(r["query_sweeps"])
 
 
 _SUBPROCESS_8DEV = r"""

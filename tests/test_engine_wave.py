@@ -126,15 +126,16 @@ def test_wave_loop_first_touch_and_max_sweeps(road):
                             max_sweeps=budget))
 
 
+@pytest.mark.parametrize("k", [4, 1030])   # 1030: gathered as 1152 slots
 @pytest.mark.parametrize("semiring", ["min_plus", "max_min", "min_select",
                                       "plus_times", "test_wave_max_times"])
-def test_wave_kernel_is_the_per_query_kernel_per_query(semiring):
+def test_wave_kernel_is_the_per_query_kernel_per_query(semiring, k):
     if semiring not in sr.SEMIRINGS:   # no reduce_fn: the generic ⊕-fold
         sr.register(sr.Semiring(name=semiring, add=jnp.maximum,
                                 mul=jnp.multiply, zero=0.0, one=1.0,
                                 improves=lambda new, old: new > old))
     rng = np.random.default_rng(5)
-    r, c, k, nq, b = 5, 7, 4, 3, 16
+    r, c, nq, b = 5, 7, 3, 16
     vals = rng.uniform(0.1, 1.0, (r, b, k * b)).astype(np.float32)
     vals[rng.random(vals.shape) < 0.5] = sr.get(semiring).zero
     cols = jnp.asarray(rng.integers(0, c, (r, k)), jnp.int32)

@@ -19,6 +19,7 @@ from repro import api, obs
 from repro.core import engine as eng
 from repro.core import graph as G
 from repro.core import placement as PL
+from repro.kernels import ref
 from repro.serve.graph import PlanStore
 
 ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
@@ -58,18 +59,18 @@ def test_graph_extent_never_below_factor_query_axis(ndev, wave, floor):
 def test_the_scale_17_graph500_plan_takes_four_chips():
     """g500_s17's plan (8,192 row-blocks in groups of 128, K 3,112)
     against a v5e's 16 GiB with waves of 8.  Half the plan (13.1 GB)
-    plus four queries' gathered source blocks of one 128-row step, at
-    128 lanes a row of 16 floats, would take 81% of the chip, over the
-    headroom; a quarter plus eight queries takes 48%."""
-    r_pad, b, k, lanes, limit = 8192, 16, 3112, 128, 16 << 30
+    plus four queries' gathered source blocks of one 128-row step and
+    their relayout, lane-dense, would take 78% of the chip, over the
+    headroom; a quarter plus eight queries takes 41%."""
+    r_pad, b, k, limit = 8192, 16, 3112, 16 << 30
     shape = (r_pad, b * k * b * 4 + k * 4 + 4 + 2 * b + 8, 128,
-             k * lanes * 4, 2 * r_pad * lanes * 4)
+             2 * k * b * 4, 2 * r_pad * b * 4)
     assert PL.graph_extent(*shape, num_devices=4, max_wave=8,
                            bytes_limit=limit) == 4
     half = PL.device_need(*shape, num_devices=4, max_wave=8, d_g=2)
     quarter = PL.device_need(*shape, num_devices=4, max_wave=8, d_g=4)
-    assert PL.HEADROOM * limit < half < 0.82 * limit
-    assert quarter < 0.49 * limit
+    assert PL.HEADROOM * limit < half < 0.79 * limit
+    assert quarter < 0.42 * limit
 
 
 @pytest.mark.parametrize("local,gb,step", [(2048, 128, 128), (16, 4, 4),
@@ -78,16 +79,23 @@ def test_rows_per_step_divides_the_local_rows(local, gb, step):
     assert PL.rows_per_step(local, gb) == step
 
 
+@pytest.mark.parametrize("q", [1, 3, 8])
 @pytest.mark.parametrize("semiring", ["min_plus", "min_select", "max_min",
                                       "plus_times"])
-def test_stepped_local_sweep_equals_one_call_over_all_rows(semiring):
+def test_stepped_local_sweep_equals_one_call_over_all_rows(semiring, q):
+    """The wave form, (C, Q, B) → (R, Q, B), stepped or in one call, is
+    the per-query kernel vmapped over the queries, bit for bit."""
     g = G.rmat(200, 900, seed=6)
     p = eng.prepare(g, semiring, b=8, num_clusters=8)
-    x = np.random.default_rng(0).random((3, p.r_pad, p.b), np.float32)
+    x = np.random.default_rng(q).random((p.r_pad, q, p.b), np.float32)
     whole = PL.local_spmv(p.vals, p.cols, p.nnz, x, semiring, p.r_pad)
     stepped = PL.local_spmv(p.vals, p.cols, p.nnz, x, semiring, p.gb)
-    assert stepped.shape == (3, p.r_pad, p.b)
+    assert p.gb < p.r_pad and stepped.shape == (p.r_pad, q, p.b)
     np.testing.assert_array_equal(np.asarray(stepped), np.asarray(whole))
+    per_query = jax.vmap(lambda xq: ref.bsr_spmv_ref(
+        p.vals, p.cols, xq, semiring), in_axes=1, out_axes=1)(x)
+    np.testing.assert_array_equal(np.asarray(stepped),
+                                  np.asarray(per_query))
 
 
 # -- one device: prepare is unchanged ----------------------------------------
@@ -163,10 +171,10 @@ out["single_equal_oracle"] = bool(np.array_equal(
 one = single.bfs(roots[:8]).values
 
 # plan row-blocks of this graph: 64 in groups of 4, K 56; with waves of
-# 8 a device needs 2,564,480 bytes at graph extent 2 and 2,363,584 at
+# 8 a device needs 1,991,040 bytes at graph extent 2 and 1,216,704 at
 # 4 (placement.device_need): a limit that admits a quarter of the plan
 # with its waves but not half (the g500_s17 cell's shape)
-LIMIT = 3_300_000
+LIMIT = 2_200_000
 PL.device_bytes_limit = lambda: LIMIT
 svc = api.GraphService(max_plan_bytes=LIMIT, max_wave=8,
                        policy=api.ExecutionPolicy(mode="distributed",
@@ -216,6 +224,7 @@ out["places_during_waves"] = len([s for s in obs.spans("plan.place")
 out["plans_built"] = proc.cache_info()["prepare_calls"]
 runs = [s.attrs for s in obs.spans("run.device")]
 out["run_meshes"] = sorted({a.get("mesh") for a in runs})
+out["run_gathers"] = sorted({(a["gather"], a["q"]) for a in runs})
 dists = list({id(r.extra["dist"]): r.extra["dist"] for r in res}.values())
 out["halo_ok"] = bool(runs) and sorted(
     (a["halo_exchanges"], a["halo_bytes"]) for a in runs) == sorted(
@@ -283,6 +292,12 @@ def test_run_device_spans_carry_the_mesh_and_halo_bytes(four):
     assert four["run_meshes"] == ["4x1"]
     assert four["halo_ok"] and four["halo_positive"]
     assert four["exchanges_are_sweeps"]
+
+
+def test_run_device_spans_say_a_distributed_wave_gathers_once(four):
+    """A distributed wave of 8 runs the wave kernel (each tile's source
+    block gathered once for the wave); the single query, per query."""
+    assert four["run_gathers"] == [["per_query", 1], ["wave", 8]]
 
 
 def test_store_charges_a_sharded_plan_by_its_fullest_device(four):
