@@ -22,9 +22,8 @@ import numpy as np  # noqa: E402
 
 import jax.numpy as jnp  # noqa: E402
 
-from repro import api  # noqa: E402
+from repro import algebra, api  # noqa: E402
 from repro.core import graph as G  # noqa: E402
-from repro.core import semiring as S  # noqa: E402
 
 
 def tour_catalog(proc):
@@ -39,7 +38,7 @@ def tour_catalog(proc):
             print(f"{name:14s} {'—':14s} {'(one-shot/host)':15s} "
                   f"{'—':>14s} {'—':>10s}")
             continue
-        rule = S.rule(a.update)
+        rule = algebra.rule(a.update)
         print(f"{name:14s} {a.semiring:14s} {a.update:15s} "
               f"{'yes':>14s} {'yes' if rule.monotone else 'no':>10s}")
 
@@ -78,8 +77,8 @@ def tour_catalog(proc):
 
 def register_reliability():
     """A new algorithm = a semiring + an AlgorithmSpec. Nothing else."""
-    if "max_times" not in S.SEMIRINGS:
-        S.register(S.Semiring(
+    if "max_times" not in algebra.SEMIRINGS:
+        algebra.register(algebra.Semiring(
             name="max_times",          # ⊕ = max, ⊗ = × over [0, 1]
             add=jnp.maximum,
             mul=jnp.multiply,
@@ -112,13 +111,17 @@ def main():
     gp = G.Graph(n=g.n, indptr=g.indptr, indices=g.indices,
                  weights=(1.0 / (1.0 + g.weights)).astype(np.float32))
     proc2 = api.GraphProcessor(gp, b=16, num_clusters=16)
-    for mode in ("sync", "async"):
+    # the registered ring runs on the ref kernels and, as well, on the
+    # Pallas kernel with the fused apply rule
+    fused = api.KernelSpec(impl="pallas", fuse_frontier=True)
+    for mode, kernel in (("sync", None), ("async", None), ("async", fused)):
         r = proc2.run(api.QuerySpec(
             algo="reliability", sources=(0,),
-            policy=api.ExecutionPolicy(mode=mode)))
+            policy=api.ExecutionPolicy(mode=mode, kernel=kernel)))
         v = np.asarray(r.values)
         reach = int((v > 0).sum())
-        print(f"reliability [{mode:5s}] reachable {reach}/{gp.n}, "
+        name = mode if kernel is None else "fused"
+        print(f"reliability [{name:5s}] reachable {reach}/{gp.n}, "
               f"best non-source path p={float(np.sort(v)[-2]):.4f}, "
               f"sweeps {r.stats.sweeps}")
 

@@ -2,7 +2,8 @@
 # architecture, adapted TPU-natively (see DESIGN.md §2).
 #   graph/cluster  — Fig.4 compile-time steps 1–4 (topology → clusters →
 #                    dependencies → placement)
-#   semiring       — the NALE MAC/comparator datapath algebra
+#   repro.algebra  — the NALE MAC/comparator datapath algebra: a leaf
+#                    module that core/ and kernels/ both read
 #   engine         — sync (BSP) vs async (cluster-dataflow, Gauss-Seidel)
 #   algorithms     — SSSP, BFS, DFS, PageRank, MiniTri, CC
 #   isa/compile    — the specialized ISA + step-5 codegen
@@ -10,4 +11,4 @@
 #   placement      — multi-device halo-exchange engine (shard_map)
 
 from . import algorithms, api, cluster, compile, engine, graph, isa, \
-    oracles, placement, power, semiring  # noqa: F401
+    oracles, placement, power  # noqa: F401

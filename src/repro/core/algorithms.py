@@ -35,7 +35,8 @@ from typing import Any, Callable, Optional, Tuple
 
 import numpy as np
 
-from . import oracles, semiring as sr
+from . import oracles
+from .. import algebra
 from .graph import Graph
 
 
@@ -45,8 +46,8 @@ class AlgorithmSpec:
 
     Attributes:
       name:        registry key; ``QuerySpec.algo`` strings resolve here.
-      semiring:    ⊕/⊗ pair the sweeps run on (``semiring.get`` name).
-      update:      apply rule name (``semiring.rule``); its
+      semiring:    ⊕/⊗ pair the sweeps run on (``algebra.get`` name).
+      update:      apply rule name (``algebra.rule``); its
                    ``monotone``/``bias``/``exact`` properties drive
                    schedule eligibility in every engine flavor.
       variant:     graph transform the plan is built over — "base",
@@ -97,13 +98,13 @@ class AlgorithmSpec:
     runner: Optional[str] = None
 
     @property
-    def rule(self) -> sr.UpdateRule:
+    def rule(self) -> algebra.UpdateRule:
         """Scheduling properties of this algorithm's update rule."""
-        return sr.rule(self.update)
+        return algebra.rule(self.update)
 
     @property
-    def ring(self) -> sr.Semiring:
-        return sr.get(self.semiring)
+    def ring(self) -> algebra.Semiring:
+        return algebra.get(self.semiring)
 
 
 ALGORITHMS: dict = {}
@@ -112,9 +113,9 @@ ALGORITHMS: dict = {}
 def register_algorithm(spec: AlgorithmSpec,
                        overwrite: bool = False) -> AlgorithmSpec:
     """Register an algorithm for ``QuerySpec``/engine/serving dispatch."""
-    sr.rule(spec.update)        # fail fast on unknown rule names
+    algebra.rule(spec.update)       # fail fast on unknown rule names
     if spec.runner is None:
-        sr.get(spec.semiring)   # ... and unknown semirings
+        algebra.get(spec.semiring)  # ... and unknown semirings
     if spec.name in ALGORITHMS and not overwrite:
         raise ValueError(
             f"algorithm {spec.name!r} is already registered; pass "

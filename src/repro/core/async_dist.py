@@ -14,7 +14,7 @@ local_sweeps=k)``):
     ``s+1`` immediately — the software analogue of values flowing
     through NALE FIFOs as soon as they are produced), remote reads come
     from the halo buffered at the start of the round.  For idempotent,
-    monotone update rules (``semiring.UPDATE_RULES``: the semiring
+    monotone update rules (``algebra.UPDATE_RULES``: the semiring
     ``relax`` of SSSP/BFS/CC/reachability, k-core peeling, and
     GraphScale's ``pagerank_delta`` accumulation) a stale remote value
     is just a not-yet-improved bound, so the fixpoint is untouched while
@@ -63,9 +63,8 @@ import jax.numpy as jnp
 import numpy as np
 from jax.sharding import Mesh, PartitionSpec as P
 
-from . import semiring as sr
-from .engine import Prepared, _apply
-from .. import resilience
+from .engine import Prepared
+from .. import algebra, resilience
 from .placement import (DistStats, ShardedBatch,  # noqa: F401 (re-export)
                         local_spmv, rows_per_step, shard_batched_inputs)
 
@@ -87,7 +86,7 @@ def distributed_async_run_batched(
     local_sweeps``.
 
     Eligibility comes from the update-rule registry
-    (``semiring.UPDATE_RULES``): the k-local-sweep schedule relies on
+    (``algebra.UPDATE_RULES``): the k-local-sweep schedule relies on
     the rule being idempotent and monotone (stale remote values are
     conservative bounds).  Classic PageRank's unconditional damped
     affine sweep is neither — use ``algo="pagerank_delta"`` (the
@@ -96,8 +95,8 @@ def distributed_async_run_batched(
     k = int(local_sweeps)
     if k < 1:
         raise ValueError(f"local_sweeps must be >= 1, got {local_sweeps}")
-    if not sr.rule(apply_kind).monotone:
-        eligible = sorted(n for n, r in sr.UPDATE_RULES.items()
+    if not algebra.rule(apply_kind).monotone:
+        eligible = sorted(n for n, r in algebra.UPDATE_RULES.items()
                           if r.monotone)
         raise ValueError(
             "dist_flavor='async' requires an idempotent monotone update "
@@ -116,7 +115,7 @@ def distributed_async_run_batched(
                     shards=d_g)
     rl = sb.r_pad // d_g            # local rows per "graph" shard
     step = rows_per_step(rl, p.gb)
-    ring = sr.get(p.semiring)
+    ring = algebra.get(p.semiring)
     inv_n = jnp.float32(1.0 / max(p.n, 1))
     damping = jnp.float32(damping)
     tol = jnp.float32(tol)
@@ -164,8 +163,8 @@ def distributed_async_run_batched(
 
         def relax(cols, xg, x):
             y = spmv(cols, xg)
-            return _apply(apply_kind, ring, y, x, valid_b, damping,
-                          inv_n, tol)
+            return algebra.apply_rule(apply_kind, ring, y, x, valid_b,
+                                      damping, inv_n, tol)
 
         def cond(st):
             i, x, done_q, lsw, sls, all_done = st

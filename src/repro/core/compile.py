@@ -15,16 +15,8 @@ from typing import Dict, List
 import numpy as np
 
 from . import isa
-from . import semiring as sr
 from .engine import Prepared
-
-# stable ISA rule ids (the GCFG operand); historical names keep their
-# ids, anything else registered in semiring.UPDATE_RULES gets one
-# appended in registration order
-APPLY_RULES = {"relax": 0, "pagerank": 1, "identity": 2}
-for _name in sr.UPDATE_RULES:
-    APPLY_RULES.setdefault(_name, len(APPLY_RULES))
-del _name
+from .. import algebra
 
 
 @dataclasses.dataclass
@@ -44,8 +36,9 @@ def compile_graph_program(p: Prepared, apply_kind: str = "relax"
     """Emit per-cluster NALE programs from the prepared (clustered) image."""
     cols = np.asarray(p.cols)
     nnz = np.asarray(p.nnz)
-    sr.rule(apply_kind)  # unknown rules fail with the registry's error
-    rule = APPLY_RULES.setdefault(apply_kind, len(APPLY_RULES))
+    bias = algebra.rule(apply_kind).bias
+    # the GCFG operand: the rule's place in the registry
+    rule = list(algebra.UPDATE_RULES).index(apply_kind)
     programs: List[isa.Program] = []
     static = np.zeros(p.s, dtype=np.int64)
     total: Dict[str, int] = {k: 0 for k in isa.OPCODES}
@@ -73,7 +66,7 @@ def compile_graph_program(p: Prepared, apply_kind: str = "relax"
                     ins.append(isa.instr("GLDX", cb))
                     loaded.add(cb)
                 ins.append(isa.instr("GMAC", k, cb))
-            if nnz[r] or sr.rule(apply_kind).bias:
+            if nnz[r] or bias:
                 ins.append(isa.instr("GCMP", r))
                 ins.append(isa.instr("GAPP", r, rule))
         for dst in sorted(ext_srcs):  # symmetric notification downstream

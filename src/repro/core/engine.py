@@ -33,13 +33,12 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-from . import semiring as sr
 from .cluster import Clustering, cluster_graph, identity_clustering
 from .graph import Graph, to_bsr
 from ..kernels import ops
 from ..kernels.bsr_spmv import lane_tiles
 from ..kernels.spec import KernelSpec, as_kernel_spec
-from .. import obs, resilience
+from .. import algebra, obs, resilience
 
 #: the widest wave of sources a served plan runs at once: the default of
 #: ``GraphService.max_wave`` and ``WavePolicy.max_wave``, and the wave a
@@ -301,7 +300,7 @@ def prepare(g: Graph, semiring_name: str, b: int = 32,
     ``mesh`` and ``shard_bytes`` (tile bytes on the fullest device), and
     on a mesh holds the ``plan.place`` span of the rows' placement.
     """
-    ring = sr.get(semiring_name)
+    ring = algebra.get(semiring_name)
     n = g.n
     if normalize == "out_stochastic":
         outdeg = np.maximum(np.diff(g.indptr), 1)
@@ -417,55 +416,6 @@ def _upload_sharded(rows: dict, groups: dict, gb: int, devices: int,
     return dev
 
 
-# ---------------------------------------------------------------------------
-# apply / convergence rules
-# ---------------------------------------------------------------------------
-
-
-def _apply(apply_kind: str, ring: sr.Semiring, y, xg, valid_g, damping,
-           inv_n, tol):
-    """Returns (x_new, improved_rows) for one block of rows.
-
-    Note: PageRank uses dangling-drop semantics (no global dangling-mass
-    redistribution; the result is L1-renormalized by the caller).  This
-    keeps the update *edge-local*, which the asynchronous model requires —
-    a global scalar input would invalidate cluster-level data-readiness
-    tracking (and is exactly the kind of global synchronization the paper's
-    architecture removes).
-    """
-    if apply_kind == "relax":
-        x_new = ring.add(y, xg)
-        imp = ring.improves(x_new, xg)
-    elif apply_kind == "pagerank":
-        x_new = (1.0 - damping) * inv_n + damping * y
-        x_new = jnp.where(valid_g, x_new, 0.0)
-        imp = jnp.abs(x_new - xg) > tol
-    elif apply_kind == "pagerank_delta":
-        # GraphScale's delta form: ranks only RISE (by > tol) from the
-        # (1-d)/n floor toward the fixpoint — conditional assignment
-        # makes the rule idempotent + monotone, so it is safe under
-        # every self-timed schedule (stale y under-estimates the rank).
-        cand = (1.0 - damping) * inv_n + damping * y
-        imp = (cand - xg) > tol
-        x_new = jnp.where(imp, cand, xg)
-    elif apply_kind == "kcore":
-        # membership peeling: y counts live neighbours (plus_times over
-        # unit weights); k rides the damping scalar slot.  Monotone-
-        # decreasing on {0,1} — a vertex dies when its live-degree
-        # drops below k and never revives.
-        alive = (xg > 0.0) & (y >= damping)
-        x_new = jnp.where(alive, xg, 0.0)
-        imp = x_new < xg
-    elif apply_kind == "identity":
-        x_new = jnp.where(valid_g, y, xg)
-        imp = ring.improves(x_new, xg)
-    else:
-        raise ValueError(apply_kind)
-    x_new = jnp.where(valid_g, x_new, xg)
-    imp = imp & valid_g
-    return x_new, imp
-
-
 @dataclasses.dataclass
 class RunStats:
     sweeps: int
@@ -535,7 +485,7 @@ def _resolve_kernel(kernel, impl: str) -> KernelSpec:
 @jax.named_scope("engine.sync_loop")
 def _sync_loop(vals, cols, nnz, valid, dangling, x0, damping, tol, inv_n,
                semiring_name, apply_kind, max_sweeps, kernel):
-    ring = sr.get(semiring_name)
+    ring = algebra.get(semiring_name)
     spmv = ops.select_kernel("bsr_spmv", kernel)
 
     def cond(st):
@@ -547,8 +497,8 @@ def _sync_loop(vals, cols, nnz, valid, dangling, x0, damping, tol, inv_n,
         with jax.named_scope("sweep.spmv"):
             y = spmv(vals, cols, nnz, x, semiring=semiring_name)
         with jax.named_scope("sweep.apply"):
-            x_new, imp = _apply(apply_kind, ring, y, x, valid, damping,
-                                inv_n, tol)
+            x_new, imp = algebra.apply_rule(apply_kind, ring, y, x, valid,
+                                            damping, inv_n, tol)
         return i + 1, x_new, ~jnp.any(imp)
 
     i, x, done = jax.lax.while_loop(cond, body, (jnp.int32(0), x0, False))
@@ -577,7 +527,7 @@ def _sync_loop_fused(vals, cols, nnz, valid, row_edges, row_ext, x0,
     lane = jnp.arange(k)[None, :]
     live = lane < nnz[:, None]
     nnz_f = nnz.astype(jnp.float32)
-    bias = sr.rule(apply_kind).bias
+    bias = algebra.rule(apply_kind).bias
     valid_rows = jnp.any(valid, axis=1)
 
     def cond(st):
@@ -664,7 +614,7 @@ def _async_loop(vals, cols, nnz, valid, dangling, group_tiles, group_edges,
                 group_ext, row_edges, row_ext, x0, changed0, damping, tol,
                 inv_n, semiring_name, apply_kind, max_sweeps, gb, s,
                 kernel):
-    ring = sr.get(semiring_name)
+    ring = algebra.get(semiring_name)
     spmv = ops.select_kernel("bsr_spmv", kernel)
     fused = kernel.fuse_frontier
     k = cols.shape[1]
@@ -672,8 +622,8 @@ def _async_loop(vals, cols, nnz, valid, dangling, group_tiles, group_edges,
 
     # apply kinds with a bias term (PageRank's (1-d)/n, k-core's
     # threshold test) must touch every cluster at least once even if it
-    # has no in-edges (registry: semiring.UPDATE_RULES).
-    first_touch = sr.rule(apply_kind).bias
+    # has no in-edges (registry: algebra.UPDATE_RULES).
+    first_touch = algebra.rule(apply_kind).bias
 
     def sweep_step(carry, sidx):
         x, ch_prev, ch_next, ran, counters = carry
@@ -714,8 +664,8 @@ def _async_loop(vals, cols, nnz, valid, dangling, group_tiles, group_edges,
                     y = spmv(vals_g, cols_g, nnz_g, x,
                              semiring=semiring_name)
                 with jax.named_scope("sweep.apply"):
-                    x_new, imp = _apply(apply_kind, ring, y, xg, vg,
-                                        damping, inv_n, tol)
+                    x_new, imp = algebra.apply_rule(
+                        apply_kind, ring, y, xg, vg, damping, inv_n, tol)
                     imp_rows = jnp.any(imp, axis=1)
             x = jax.lax.dynamic_update_slice_in_dim(x, x_new, row0, 0)
             ch_next = jax.lax.dynamic_update_slice_in_dim(
@@ -862,11 +812,11 @@ def _async_wave_loop(vals, cols, nnz, valid, group_tiles, group_edges,
     straggler sweeps.  x0: (Q, r_pad, B), changed0: (Q, r_pad); returns
     per-query sweeps, x as (Q, r_pad, B), done and counters (each (Q,)).
     """
-    ring = sr.get(semiring_name)
+    ring = algebra.get(semiring_name)
     spmv = ops.select_kernel("bsr_spmv_wave", kernel)
     nq, r_pad, b = x0.shape
     lane = jnp.arange(cols.shape[1])[None, :, None]
-    first_touch = sr.rule(apply_kind).bias
+    first_touch = algebra.rule(apply_kind).bias
 
     def sweep_step(carry, sidx):
         # ch: the flags the frontier test reads, last sweep's | this
@@ -894,8 +844,8 @@ def _async_wave_loop(vals, cols, nnz, valid, group_tiles, group_edges,
             y = spmv(vals_g, cols_g, nnz_g, x.reshape(r_pad, nq, b),
                      semiring=semiring_name).reshape(gb, nq * b)
         with jax.named_scope("sweep.apply"):
-            x_new, imp = _apply(apply_kind, ring, y, xg, vg, damping,
-                                inv_n, tol)
+            x_new, imp = algebra.apply_rule(apply_kind, ring, y, xg, vg,
+                                            damping, inv_n, tol)
             x_new = jnp.where(jnp.repeat(active, b), x_new, xg)
             imp_rows = jnp.any(imp.reshape(gb, nq, b), axis=2) & active
         x = jax.lax.dynamic_update_slice_in_dim(x, x_new, row0, 0)

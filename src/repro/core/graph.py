@@ -25,7 +25,7 @@ from typing import Optional
 
 import numpy as np
 
-from . import semiring as sr
+from .. import algebra
 
 
 @dataclasses.dataclass
@@ -210,7 +210,7 @@ def to_bsr(g: Graph, b: int, pad_value: float = 0.0,
     padded tiles / absent intra-tile edges contribute nothing (for
     plus_times: 0; min_plus: +inf; max_min: 0).
     """
-    pad_value = float(sr.get(semiring_name).zero) if pad_value is None else pad_value
+    pad_value = float(algebra.get(semiring_name).zero) if pad_value is None else pad_value
     r = (g.n + b - 1) // b
     src = np.repeat(np.arange(g.n, dtype=np.int64), np.diff(g.indptr))
     dst = g.indices.astype(np.int64)

@@ -29,9 +29,8 @@ import jax.numpy as jnp
 import numpy as np
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
-from . import semiring as sr
-from .engine import Prepared, _apply
-from .. import obs, resilience
+from .engine import Prepared
+from .. import algebra, obs, resilience
 from ..kernels import ops
 from ..kernels.spec import KernelSpec
 
@@ -332,7 +331,7 @@ def shard_batched_inputs(p: Prepared, x0: jnp.ndarray,
     # padding rows hold the ⊕-identity so they never win a reduction
     # (inf for the min semirings, 0 for plus_times/max_min — the value
     # np.pad already wrote, so this is a no-op there)
-    x0[:, p.r_pad:] = sr.get(p.semiring).zero
+    x0[:, p.r_pad:] = algebra.get(p.semiring).zero
     # padding queries start converged: frozen from sweep 0, zero work
     qlive = np.arange(q_pad) < Q
     return ShardedBatch(mesh=mesh, d_g=d_g, d_q=d_q, r_pad=r_pad,
@@ -355,7 +354,7 @@ def distributed_sync_run(
                     shards=d)
     resilience.fire("dist.dispatch", flavor="sync", batched=False,
                     shards=d)
-    ring = sr.get(p.semiring)
+    ring = algebra.get(p.semiring)
 
     r_pad = ((p.r_pad + d - 1) // d) * d
     step = rows_per_step(r_pad // d, p.gb)
@@ -445,8 +444,10 @@ def wave_sweep(vals, cols, nnz, valid, x, semiring: str, apply_kind: str,
     xg = jax.lax.all_gather(x, "graph", tiled=True)
     y = local_spmv(vals, cols, nnz, xg.reshape(-1, qb // b, b), semiring,
                    step)
-    return _apply(apply_kind, sr.get(semiring), y.reshape(r, qb), x,
-                  jnp.tile(valid, (1, qb // b)), damping, inv_n, tol)
+    return algebra.apply_rule(apply_kind, algebra.get(semiring),
+                              y.reshape(r, qb), x,
+                              jnp.tile(valid, (1, qb // b)), damping,
+                              inv_n, tol)
 
 
 @functools.lru_cache(maxsize=64)

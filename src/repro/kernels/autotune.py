@@ -39,9 +39,9 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from .. import algebra
 from ..launch.roofline import PEAKS, kernel_roofline
 from . import ops
-from .bsr_spmv import _init_val
 from .spec import KernelSpec
 
 CALIBRATION_DENSITY = 0.25
@@ -88,7 +88,7 @@ def _calibration_inputs(p, seed: int, apply_kind: str):
     """Seeded synthetic state on the plan's real tile structure."""
     rng = np.random.default_rng(seed)
     r_pad, b = int(p.r_pad), int(p.b)
-    zero = _init_val(p.semiring)
+    zero = algebra.get(p.semiring).zero
     x = jnp.asarray(np.where(
         rng.random((r_pad, b)) < 0.5, rng.random((r_pad, b)), zero),
         jnp.float32)

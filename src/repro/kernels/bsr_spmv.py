@@ -28,6 +28,9 @@ per-step tile bound is scalar-prefetched into SMEM: it steers the
 ``pl.when`` skip and clamps the chunk index of the block maps onto the
 last live chunk, so dead chunks are not even fetched.  Rows whose own
 count ends inside a chunk mask the rest of it to the ⊕-identity.
+
+What ⊕, ⊗, the ⊕-identity and each update rule compute is read from
+``repro.algebra``, so any registered ring or rule runs here.
 """
 
 from __future__ import annotations
@@ -40,21 +43,10 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
+from .. import algebra
+
 SUBLANES = 8   # row-blocks per grid-step group: one f32 sublane tile
 LANES = 128
-
-
-def _init_val(semiring: str) -> float:
-    return {"plus_times": 0.0, "min_plus": jnp.inf,
-            "max_min": 0.0, "min_select": jnp.inf}[semiring]
-
-
-def _acc(semiring: str, a, b):
-    if semiring == "plus_times":
-        return a + b
-    if semiring in ("min_plus", "min_select"):
-        return jnp.minimum(a, b)
-    return jnp.maximum(a, b)
 
 
 def lane_tiles(b: int) -> int:
@@ -72,24 +64,15 @@ def _chunk_tiles(bk: int, b: int, k: int) -> int:
     return k if c >= k else c
 
 
-def _combine(semiring: str, b: int, base, vals, xs, nnz):
+def _combine(ring: algebra.Semiring, b: int, base, vals, xs, nnz):
     """One chunk of NALE MACs for a row group: (G, B, C) tile values ⊗
     (G, C) gathered sources, ⊕-reduced over the chunk's lanes -> (G, B).
     Lanes of tiles at or past a row's ``nnz`` (G, 1) — padding, or the
     ragged last chunk read past K — are masked to the ⊕-identity."""
     lane = jax.lax.broadcasted_iota(jnp.int32, (1, xs.shape[1]), 1)
     live = (lane < (nnz - base) * b)[:, None, :]
-    w = jnp.where(live, vals, _init_val(semiring))
-    xs = xs[:, None, :]
-    if semiring == "plus_times":
-        return jnp.sum(w * xs, axis=2)
-    if semiring == "min_plus":
-        return jnp.min(w + xs, axis=2)
-    if semiring == "max_min":
-        return jnp.max(jnp.minimum(w, xs), axis=2)
-    if semiring == "min_select":
-        return jnp.min(jnp.where(jnp.isfinite(w), xs, jnp.inf), axis=2)
-    raise ValueError(semiring)
+    w = jnp.where(live, vals, ring.zero)
+    return ring.reduce(ring.mul(w, xs[:, None, :]), axis=2)
 
 
 def _last_chunk(n, ct: int):
@@ -114,19 +97,20 @@ def _operands(block_vals, block_cols, block_nnz, x, rows: int, bk: int):
 
 def _bsr_spmv_kernel(snnz_ref, vals_ref, xs_ref, nnz_ref, y_ref, *,
                      semiring: str, b: int, ct: int):
+    ring = algebra.get(semiring)
     i, kc = pl.program_id(0), pl.program_id(1)
 
     @pl.when(kc == 0)
     def _():
-        y_ref[...] = jnp.full(y_ref.shape, _init_val(semiring), jnp.float32)
+        y_ref[...] = jnp.full(y_ref.shape, ring.zero, jnp.float32)
 
     base = kc * ct
 
     @pl.when(snnz_ref[i] > base)   # self-timed bound of the whole step
     def _():
-        part = _combine(semiring, b, base, vals_ref[...], xs_ref[...],
+        part = _combine(ring, b, base, vals_ref[...], xs_ref[...],
                         nnz_ref[...])
-        y_ref[...] = _acc(semiring, y_ref[...], part)
+        y_ref[...] = ring.add(y_ref[...], part)
 
 
 @functools.partial(jax.jit, static_argnames=(
@@ -196,52 +180,12 @@ def bsr_spmv(block_vals: jnp.ndarray, block_cols: jnp.ndarray,
 # int32, and every visited group writes all of its outputs, so no output
 # block is read before it is written.
 
-# the update rules below mirror core/engine._apply op-for-op (same jnp
-# primitives ⇒ same lowering ⇒ bit-identical results); they live here
-# because kernels/ must not import core/ (core.__init__ imports engine,
-# which imports kernels.ops)
-
-
-def _improves(semiring: str, new, old):
-    if semiring == "plus_times":
-        return new != old
-    if semiring == "max_min":
-        return new > old
-    return new < old  # min_plus, min_select
-
-
-def _apply_rows(apply_kind: str, semiring: str, y, xg, vg, damping, inv_n,
-                tol):
-    """(x_new, improved) for one row-block; mirrors core/engine._apply."""
-    if apply_kind == "relax":
-        x_new = _acc(semiring, y, xg)   # _acc IS the ⊕ of the semiring
-        imp = _improves(semiring, x_new, xg)
-    elif apply_kind == "pagerank":
-        x_new = (1.0 - damping) * inv_n + damping * y
-        x_new = jnp.where(vg, x_new, 0.0)
-        imp = jnp.abs(x_new - xg) > tol
-    elif apply_kind == "pagerank_delta":
-        cand = (1.0 - damping) * inv_n + damping * y
-        imp = (cand - xg) > tol
-        x_new = jnp.where(imp, cand, xg)
-    elif apply_kind == "kcore":
-        alive = (xg > 0.0) & (y >= damping)
-        x_new = jnp.where(alive, xg, 0.0)
-        imp = x_new < xg
-    elif apply_kind == "identity":
-        x_new = jnp.where(vg, y, xg)
-        imp = _improves(semiring, x_new, xg)
-    else:
-        raise ValueError(apply_kind)
-    x_new = jnp.where(vg, x_new, xg)
-    imp = imp & vg
-    return x_new, imp
-
 
 def _fused_kernel(na_ref, al_ref, gnnz_ref, par_ref, vals_ref, xs_ref,
                   nnz_ref, xg_ref, vg_ref, xa_ref, ch0_ref,
                   xo_ref, cho_ref, conv_ref, *,
                   semiring: str, apply_kind: str, b: int, ct: int, nk: int):
+    ring = algebra.get(semiring)
     i, kc = pl.program_id(0), pl.program_id(1)
     del xa_ref, ch0_ref  # aliased output bases; never read in-kernel
     na = na_ref[0]
@@ -263,22 +207,21 @@ def _fused_kernel(na_ref, al_ref, gnnz_ref, par_ref, vals_ref, xs_ref,
     # values stay readable in the unaliased xg operand until the apply
     @pl.when(live_step & (kc == 0))
     def _():
-        xo_ref[...] = jnp.full(xo_ref.shape, _init_val(semiring),
-                               jnp.float32)
+        xo_ref[...] = jnp.full(xo_ref.shape, ring.zero, jnp.float32)
 
     base = kc * ct
 
     @pl.when(live_step & (gnnz_ref[al_ref[i]] > base))
     def _():
-        part = _combine(semiring, b, base, vals_ref[...], xs_ref[...],
+        part = _combine(ring, b, base, vals_ref[...], xs_ref[...],
                         nnz_ref[...])
-        xo_ref[...] = _acc(semiring, xo_ref[...], part)
+        xo_ref[...] = ring.add(xo_ref[...], part)
 
     @pl.when(live_step & (kc == nk - 1))
     def _():
-        x_new, imp = _apply_rows(apply_kind, semiring, xo_ref[...],
-                                 xg_ref[...], vg_ref[...] != 0,
-                                 par_ref[0], par_ref[2], par_ref[1])
+        x_new, imp = algebra.apply_rule(apply_kind, ring, xo_ref[...],
+                                        xg_ref[...], vg_ref[...] != 0,
+                                        par_ref[0], par_ref[2], par_ref[1])
         xo_ref[...] = x_new
         ch = jnp.max(imp.astype(jnp.int32), axis=1, keepdims=True)
         cho_ref[...] = ch

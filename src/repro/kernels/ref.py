@@ -9,6 +9,7 @@ from __future__ import annotations
 import jax
 import jax.numpy as jnp
 
+from .. import algebra
 from .bsr_spmv import LANES
 
 
@@ -27,35 +28,14 @@ def bsr_spmv_ref(block_vals: jnp.ndarray, block_cols: jnp.ndarray,
       block_cols: (R, K) int32 col-block ids (padding points anywhere; the
         padded tile's values are ⊕-identities so the result is unaffected).
       x: (C, B) input vector in block layout.
-      semiring: any registered semiring name.  The four built-ins get
-        hand-fused einsum/min/max paths; anything else falls back to the
-        ring's own mul + generic ⊕-reduce (correct for every semiring
-        whose ⊕-identity absorbs under ⊗ — the ``semiring.register``
-        contract).
+      semiring: any registered semiring name (``algebra.get``); each
+        tile is contracted by ``Semiring.contract``.
     Returns:
       y: (R, B).
     """
     r = block_vals.shape[0]
     xs = x[block_cols].reshape(r, 1, -1)  # (R, 1, K*B), sources on lanes
-    if semiring == "plus_times":
-        # full f32 products: TPU's default matmul precision is bf16
-        return jnp.einsum("rij,rj->ri", block_vals, xs[:, 0],
-                          precision=jax.lax.Precision.HIGHEST)
-    if semiring == "min_plus":
-        return jnp.min(block_vals + xs, axis=2)
-    if semiring == "max_min":
-        return jnp.max(jnp.minimum(block_vals, xs), axis=2)
-    if semiring == "min_select":
-        # mul(w, x) = x when an edge exists; absent edges hold +inf weight.
-        t = jnp.where(jnp.isfinite(block_vals), xs, jnp.inf)
-        return jnp.min(t, axis=2)
-    # registered custom semiring: generic ⊗-then-⊕ over the tile and
-    # source axes.  Imported lazily — this runs post-import (kernels/
-    # must not import core/ at module load; core.__init__ → engine →
-    # kernels.ops would cycle).
-    from ..core import semiring as _sr
-    ring = _sr.get(semiring)
-    return ring.reduce(ring.mul(block_vals, xs), axis=2)
+    return algebra.get(semiring).contract(block_vals, xs)
 
 
 def bsr_spmv_wave_ref(block_vals: jnp.ndarray, block_cols: jnp.ndarray,
@@ -95,19 +75,7 @@ def bsr_spmv_wave_ref(block_vals: jnp.ndarray, block_cols: jnp.ndarray,
     if pad:
         xs = xs[:, :, :k]
     xs = xs.reshape(r, q, 1, k * b)                   # (R, Q, 1, K*B)
-    if semiring == "plus_times":
-        return jnp.einsum("rij,rqj->rqi", block_vals, xs[:, :, 0],
-                          precision=jax.lax.Precision.HIGHEST)
-    v = block_vals[:, None]                           # (R, 1, B, K*B)
-    if semiring == "min_plus":
-        return jnp.min(v + xs, axis=3)
-    if semiring == "max_min":
-        return jnp.max(jnp.minimum(v, xs), axis=3)
-    if semiring == "min_select":
-        return jnp.min(jnp.where(jnp.isfinite(v), xs, jnp.inf), axis=3)
-    from ..core import semiring as _sr
-    ring = _sr.get(semiring)
-    return ring.reduce(ring.mul(v, xs), axis=3)
+    return algebra.get(semiring).contract(block_vals, xs)
 
 
 # ---------------------------------------------------------------------------

@@ -15,11 +15,10 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
-from repro import api
+from repro import algebra, api
 from repro.core import algorithms as A
 from repro.core import graph as G
 from repro.core import oracles as O
-from repro.core import semiring as S
 
 GRAPHS = {
     "road": lambda: G.road_network(14, seed=1),
@@ -257,8 +256,8 @@ def test_registry_introspection():
         assert want in names
     spec = api.get_algorithm("pagerank_delta")
     assert spec.semiring == "plus_times"
-    assert S.rule(spec.update).monotone
-    assert not S.rule("pagerank").monotone
+    assert algebra.rule(spec.update).monotone
+    assert not algebra.rule("pagerank").monotone
     with pytest.raises(ValueError, match="unknown algorithm"):
         api.get_algorithm("warp")
 
@@ -272,8 +271,8 @@ def _max_times_ring():
     """Best-reliability ring over [0, 1] weights: ⊕ = max, ⊗ = ×.
     zero=0.0 absorbs under ⊗ (the register() contract)."""
     name = "test_max_times"
-    if name not in S.SEMIRINGS:
-        S.register(S.Semiring(
+    if name not in algebra.SEMIRINGS:
+        algebra.register(algebra.Semiring(
             name=name,
             add=jnp.maximum,
             mul=jnp.multiply,
@@ -282,7 +281,7 @@ def _max_times_ring():
             improves=lambda new, old: new > old,
             reduce_fn=lambda x, axis=None: jnp.max(x, axis=axis),
         ))
-    return S.get(name)
+    return algebra.get(name)
 
 
 def test_custom_semiring_reduce_is_a_field():
@@ -295,9 +294,9 @@ def test_custom_semiring_reduce_is_a_field():
     np.testing.assert_allclose(ring.reduce(x, axis=(0, 2)),
                                np.max(np.asarray(x), axis=(0, 2)))
     # a ring registered with reduce_fn=None gets the generic ⊕-fold
-    noname = S.Semiring(name="test_fold", add=jnp.maximum, mul=jnp.multiply,
-                        zero=0.0, one=1.0,
-                        improves=lambda new, old: new > old)
+    noname = algebra.Semiring(name="test_fold", add=jnp.maximum,
+                              mul=jnp.multiply, zero=0.0, one=1.0,
+                              improves=lambda new, old: new > old)
     np.testing.assert_allclose(np.asarray(noname.reduce(x, axis=(1,))),
                                np.max(np.asarray(x), axis=1), rtol=1e-6)
     np.testing.assert_allclose(float(noname.reduce(x)),
@@ -362,3 +361,66 @@ def test_custom_algorithm_end_to_end():
                                    policy=api.ExecutionPolicy(mode=mode)))
         np.testing.assert_allclose(np.asarray(r.values), oracle(g, 0),
                                    rtol=1e-5, atol=1e-6)
+
+
+def _within_radius_rule():
+    """Shortest paths no longer than a radius, which rides ``damping``;
+    vertices farther away stay unreached (+inf).  Monotone and exact: a
+    distance inside the radius only shrinks, so it never leaves it."""
+    name = "test_within_radius"
+    if name not in algebra.UPDATE_RULES:
+        def within(ring, y, x, valid, radius, inv_n, tol):
+            x_new = ring.add(y, x)
+            x_new = jnp.where(x_new <= radius, x_new, jnp.inf)
+            return x_new, ring.improves(x_new, x)
+        algebra.register_rule(algebra.UpdateRule(
+            name, within, bias=False, monotone=True, exact=True))
+    return name
+
+
+@pytest.mark.parametrize("engine", ["sync", "async", "wave", "fused"])
+def test_registered_rule_runs_through_every_engine(engine):
+    """A rule registered with ``register_rule`` runs on the sync and
+    async engines, the batched wave loop and the fused Pallas kernel,
+    and equals a NumPy Bellman-Ford cut at the radius."""
+    from repro.core import engine as eng
+    from repro.kernels.spec import KernelSpec
+    rule = _within_radius_rule()
+    g = G.rmat(120, 600, seed=5)
+    # whole weights: every path length is exact in float32
+    g = G.Graph(n=g.n, indptr=g.indptr, indices=g.indices,
+                weights=np.floor(g.weights))
+    radius = 6.0
+    heads = np.repeat(np.arange(g.n), np.diff(g.indptr))
+
+    def oracle(src):
+        d = np.full(g.n, np.inf, np.float32)
+        d[src] = 0.0
+        while True:
+            nxt = d.copy()
+            np.minimum.at(nxt, g.indices, d[heads] + g.weights)
+            nxt[nxt > radius] = np.inf
+            if np.array_equal(nxt, d):
+                return d
+            d = nxt
+
+    sources = [int(np.argmax(np.diff(g.indptr))), 1]
+    want = [oracle(s) for s in sources]
+    # the radius cuts off vertices the source reaches
+    assert (np.isfinite(O.sssp_oracle(g, sources[0]))
+            & ~np.isfinite(want[0])).any()
+    assert np.isfinite(want[0]).sum() > 1
+    p = eng.prepare(g, "min_plus", b=8, num_clusters=4)
+    x0 = [p.to_blocks(np.where(np.arange(g.n) == s, 0.0, np.inf)
+                      .astype(np.float32), np.inf) for s in sources]
+    if engine == "wave":
+        x, _ = eng.run_async_batched(p, jnp.stack(x0), rule, damping=radius)
+        got = [p.from_blocks(xq) for xq in x]
+    else:
+        run = eng.run_sync if engine == "sync" else eng.run_async
+        kernel = (KernelSpec(impl="pallas", fuse_frontier=True)
+                  if engine == "fused" else None)
+        got = [p.from_blocks(run(p, x, rule, damping=radius,
+                                 kernel=kernel)[0]) for x in x0]
+    for g_, w_ in zip(got, want):
+        np.testing.assert_array_equal(g_, w_)

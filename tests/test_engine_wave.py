@@ -11,10 +11,9 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
-from repro import api
+from repro import algebra, api
 from repro.core import engine as eng
 from repro.core import graph as G
-from repro.core import semiring as sr
 from repro.kernels import ops, ref
 from repro.kernels.spec import KernelSpec
 
@@ -130,14 +129,14 @@ def test_wave_loop_first_touch_and_max_sweeps(road):
 @pytest.mark.parametrize("semiring", ["min_plus", "max_min", "min_select",
                                       "plus_times", "test_wave_max_times"])
 def test_wave_kernel_is_the_per_query_kernel_per_query(semiring, k):
-    if semiring not in sr.SEMIRINGS:   # no reduce_fn: the generic ⊕-fold
-        sr.register(sr.Semiring(name=semiring, add=jnp.maximum,
-                                mul=jnp.multiply, zero=0.0, one=1.0,
-                                improves=lambda new, old: new > old))
+    if semiring not in algebra.SEMIRINGS:  # no reduce_fn: a generic ⊕-fold
+        algebra.register(algebra.Semiring(
+            name=semiring, add=jnp.maximum, mul=jnp.multiply, zero=0.0,
+            one=1.0, improves=lambda new, old: new > old))
     rng = np.random.default_rng(5)
     r, c, nq, b = 5, 7, 3, 16
     vals = rng.uniform(0.1, 1.0, (r, b, k * b)).astype(np.float32)
-    vals[rng.random(vals.shape) < 0.5] = sr.get(semiring).zero
+    vals[rng.random(vals.shape) < 0.5] = algebra.get(semiring).zero
     cols = jnp.asarray(rng.integers(0, c, (r, k)), jnp.int32)
     x = rng.uniform(0.0, 1.0, (c, nq, b)).astype(np.float32)
     x[rng.random(x.shape) < 0.2] = np.inf if semiring in (

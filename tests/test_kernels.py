@@ -6,11 +6,21 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
+from repro import algebra
 from repro.core import graph as G
-from repro.core import semiring as sr
 from repro.kernels import ops, ref
 
-SEMIRINGS = ["plus_times", "min_plus", "max_min", "min_select"]
+# a ring registered as a user would: best reliability, ⊕ = max, ⊗ = ×
+MAX_TIMES = "test_kernels_max_times"
+if MAX_TIMES not in algebra.SEMIRINGS:
+    algebra.register(algebra.Semiring(
+        name=MAX_TIMES, add=jnp.maximum, mul=jnp.multiply, zero=0.0,
+        one=1.0, improves=lambda new, old: new > old,
+        reduce_fn=lambda x, axis=None: jnp.max(x, axis=axis)))
+
+SEMIRINGS = ["plus_times", "min_plus", "max_min", "min_select", "or_and",
+             MAX_TIMES]
+BOOLEAN = ("max_min", "or_and")   # the {0,1} carrier
 
 
 def _dense_spmv(a, x, name):
@@ -18,8 +28,10 @@ def _dense_spmv(a, x, name):
         return a @ x
     if name == "min_plus":
         return np.min(a + x[None, :], axis=1)
-    if name == "max_min":
+    if name in BOOLEAN:
         return np.max(np.minimum(a, x[None, :]), axis=1)
+    if name == MAX_TIMES:
+        return np.max(a * x[None, :], axis=1)
     return np.min(np.where(np.isfinite(a), x[None, :], np.inf), axis=1)
 
 
@@ -28,9 +40,9 @@ def _dense_spmv(a, x, name):
                                       (120, 900, 32, 8)])
 def test_bsr_spmv_sweep(semiring, n, e, b, bk, rng):
     g = G.rmat(n, e, seed=n + e)
-    bsr = G.to_bsr(g, b=b, pad_value=float(sr.get(semiring).zero))
+    bsr = G.to_bsr(g, b=b, pad_value=float(algebra.get(semiring).zero))
     x = rng.random((bsr.r, bsr.b)).astype(np.float32)
-    if semiring == "max_min":
+    if semiring in BOOLEAN:
         x = (x > 0.5).astype(np.float32)
     args = (jnp.asarray(bsr.block_vals), jnp.asarray(bsr.block_cols),
             jnp.asarray(bsr.block_nnz), jnp.asarray(x))
@@ -78,7 +90,7 @@ def test_bsr_padding_is_noop(rng):
     the result (the kernel's 'empty FIFO slot' invariant)."""
     g = G.rmat(50, 200, seed=3)
     for name in SEMIRINGS:
-        z = float(sr.get(name).zero)
+        z = float(algebra.get(name).zero)
         bsr = G.to_bsr(g, b=8, pad_value=z)
         x = rng.random((bsr.r, bsr.b)).astype(np.float32)
         y0 = ops.bsr_spmv(jnp.asarray(bsr.block_vals),
@@ -103,7 +115,7 @@ def test_rows_per_step_matches_single_row(rows_per_step, rng):
     accumulation order is untouched, so the result is unchanged."""
     g = G.rmat(100, 500, seed=9)
     for name in SEMIRINGS:
-        bsr = G.to_bsr(g, b=8, pad_value=float(sr.get(name).zero))
+        bsr = G.to_bsr(g, b=8, pad_value=float(algebra.get(name).zero))
         x = rng.random((bsr.r, bsr.b)).astype(np.float32)
         from repro.kernels.spec import KernelSpec
         args = (jnp.asarray(bsr.block_vals), jnp.asarray(bsr.block_cols),
@@ -121,15 +133,14 @@ def _fused_oracle(bsr, x, valid, act, semiring, apply_kind="relax",
                   damping=0.85, tol=1e-6, inv_n=1e-2):
     """Unfused reference composition: ref SpMV -> engine apply rule ->
     frontier mask.  Rows outside ``act`` pass through bitwise."""
-    from repro.core import semiring as S
-    from repro.core.engine import _apply
     y = ops.bsr_spmv(jnp.asarray(bsr.block_vals),
                      jnp.asarray(bsr.block_cols),
                      jnp.asarray(bsr.block_nnz), jnp.asarray(x),
                      semiring=semiring, impl="ref")
-    x_new, imp = _apply(apply_kind, S.get(semiring), y, jnp.asarray(x),
-                        jnp.asarray(valid), jnp.float32(damping),
-                        jnp.float32(inv_n), jnp.float32(tol))
+    x_new, imp = algebra.apply_rule(
+        apply_kind, algebra.get(semiring), y, jnp.asarray(x),
+        jnp.asarray(valid), jnp.float32(damping), jnp.float32(inv_n),
+        jnp.float32(tol))
     x_exp = np.where(act[:, None], np.asarray(x_new), x)
     ch_exp = act & np.any(np.asarray(imp), axis=1)
     return x_exp, ch_exp
@@ -154,9 +165,9 @@ def test_fused_matches_unfused_composition(semiring, frontier, rng):
     mask: EXACT for the comparison semirings, float-accumulation
     tolerance for plus_times (different reduction grouping)."""
     g = G.rmat(120, 700, seed=11)
-    bsr = G.to_bsr(g, b=8, pad_value=float(sr.get(semiring).zero))
+    bsr = G.to_bsr(g, b=8, pad_value=float(algebra.get(semiring).zero))
     x = rng.random((bsr.r, bsr.b)).astype(np.float32)
-    if semiring == "max_min":
+    if semiring in BOOLEAN:
         x = (x > 0.5).astype(np.float32)
     valid = np.ones((bsr.r, bsr.b), bool)
     act = {"empty": np.zeros(bsr.r, bool),
